@@ -65,6 +65,7 @@ from repro.configs.registry import get_config
 from repro.models.attention import _broadcast_kv
 from repro.models.model import model_specs
 from repro.models.params import init_params
+from repro.runtime import interpret_kernels
 from repro.serve.decode import decode_step
 from repro.serve.decode_state import (
     landmark_counts,
@@ -256,16 +257,19 @@ def _pool_cell(rows, cfg, params, horizon: int, mode: str, tokens: int,
     tables[0, : len(row)] = row
     cache = _synthetic_cache(mcfg, horizon, pos0, jax.random.PRNGKey(1))
     kv.write_prefill(0, cache, tables[0], n_tokens=pos0 + 1)
-    step = functools.partial(decode_step, params, mcfg, seq_max=horizon)
+    step = functools.partial(decode_step, cfg=mcfg, seq_max=horizon)
     if impl == "paged":
-        pstep = functools.partial(
-            step, paged_meta=(block, mcfg.kernels_interpret)
-        )
+        meta = (block, interpret_kernels())
         fused = kv.make_paged_step(
-            lambda c, t, tb: pstep(c, t, paged_table=tb)
+            lambda p, c, t, tb: step(
+                p, cache=c, tokens=t, paged_table=tb, paged_meta=meta
+            ),
+            params,
         )
     else:
-        fused = kv.make_fused_step(jax.vmap(step))
+        fused = kv.make_fused_step(
+            lambda p, c, t: step(p, cache=c, tokens=t), params
+        )
     nb = kv.view_blocks_needed(np.asarray([horizon - 1]), [0])
     tok = np.ones((1, 1, 1), np.int32)
     active = np.asarray([True])
@@ -318,7 +322,7 @@ def _pool_cell(rows, cfg, params, horizon: int, mode: str, tokens: int,
         from repro.telemetry.accounting import compiled_cost
 
         cost = compiled_cost(
-            fused._jitted, kv._storage, jnp.asarray(tables)[:, :nb],
+            fused._jitted, kv._storage, params, jnp.asarray(tables)[:, :nb],
             jnp.asarray(tok), jnp.asarray([pos0 + 2], np.int32),
             jnp.asarray(active),
         )

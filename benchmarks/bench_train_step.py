@@ -33,6 +33,7 @@ import jax.numpy as jnp
 
 from repro.core.attention import SSConfig, spectral_shift_attention
 from repro.kernels.ops import ss_attention_fused
+from repro.runtime import interpret_kernels
 
 
 def _smoke() -> bool:
@@ -125,7 +126,7 @@ def _model_cell(rows, seq_len, reps):
 
 
 def run(rows: list[str]) -> None:
-    interpret = jax.default_backend() == "cpu"
+    interpret = interpret_kernels()
     if _smoke():
         _attention_cell(rows, 512, 32, 64, False, reps=1, interpret=interpret)
         _model_cell(rows, 128, reps=1)

@@ -34,6 +34,7 @@ from benchmarks import (
     bench_train_step,
     roofline,
 )
+from repro.runtime import enable_compile_cache
 
 SUITES = {
     "complexity": bench_complexity.run,      # paper Table 1
@@ -106,6 +107,7 @@ def main() -> None:
     ap.add_argument("--smoke", action="store_true",
                     help="reduced cell grids (same as REPRO_BENCH_SMOKE=1)")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.smoke:
         os.environ["REPRO_BENCH_SMOKE"] = "1"
     selected = set(args.suites)

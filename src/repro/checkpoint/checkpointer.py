@@ -29,8 +29,7 @@ _SEP = "::"
 
 def _flatten(tree) -> dict[str, Any]:
     flat = {}
-    # jax.tree.flatten_with_path needs newer jax; tree_util spelling works.
-    for path, leaf in jax.tree_util.tree_flatten_with_path(tree)[0]:
+    for path, leaf in jax.tree.flatten_with_path(tree)[0]:
         key = _SEP.join(
             str(getattr(p, "key", getattr(p, "idx", getattr(p, "name", p))))
             for p in path

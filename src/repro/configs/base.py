@@ -51,8 +51,6 @@ class ModelConfig:
                                        # sharded-seq (context-parallel) runs
     cast_params_once: bool = True      # bf16 working copy cast at step entry
                                        # (collectives move bf16, not fp32)
-    kernels_interpret: bool = True     # Pallas interpret mode (CPU); the TPU
-                                       # launcher flips this to False
     attention_backend: str = "auto"    # kernel route for *_fused impls:
                                        # auto (dispatch registry) | fused |
                                        # jnp | interpret (forced)
@@ -60,7 +58,7 @@ class ModelConfig:
                                        # keys (kernels/dispatch.py); winners
                                        # persist to the on-disk cache
     autotune_cache: str = ""           # cache path override ("" = default
-                                       # REPRO_AUTOTUNE_CACHE / ~/.cache)
+                                       # REPRO_AUTOTUNE_CACHE / <repo>/.autotune)
     seq_shard_fused: bool = True       # context-parallel cells keep the fused
                                        # Pallas path via the shard_map driver
                                        # (kernels/sharded.py); False restores

@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.landmarks import segment_means, segment_of
+from repro.core.pinv import CORE_PRECISION
 from repro.core.spectral_shift import ss_core
 
 
@@ -165,7 +166,11 @@ def _ss_factors(q, k, cfg: SSConfig, scale, q_landmarks=None, k_landmarks=None):
         seg = -(-n_k // m)
         b_mask = jnp.arange(n_k)[None, :] < (jnp.arange(c)[:, None] + 1) * seg
     f = _softmax(jnp.einsum("...qd,...cd->...qc", q, k_l) * scale, f_mask)
-    a = _softmax(jnp.einsum("...cd,...ed->...ce", q_l, k_l) * scale, a_mask)
+    a = _softmax(
+        jnp.einsum("...cd,...ed->...ce", q_l, k_l, precision=CORE_PRECISION)
+        * scale,
+        a_mask,
+    )
     b = _softmax(jnp.einsum("...cd,...kd->...ck", q_l, k) * scale, b_mask)
     return f, a, b
 
@@ -216,13 +221,15 @@ def spectral_shift_attention(
                 core.z,
                 jnp.eye(c_count, dtype=core.z.dtype)
                 - (core.delta * (c_count / k.shape[-2])) * core.z,
+                precision=CORE_PRECISION,
             ),
         )
     if cfg.variant == "eq10_literal":
         # Literal paper eq. (10): U = A^+ (I - delta A)  [typo'd form, kept
         # for faithfulness comparison — see DESIGN.md §2.1].
         c = a.shape[-1]
-        u = jnp.matmul(core.z, jnp.eye(c, dtype=a.dtype) - core.delta * a)
+        u = jnp.matmul(core.z, jnp.eye(c, dtype=a.dtype) - core.delta * a,
+                       precision=CORE_PRECISION)
     else:
         u = core.u
     if cfg.causal:
@@ -236,7 +243,8 @@ def spectral_shift_attention(
         u = jnp.where(tril, u, 0.0)
     v32 = v.astype(jnp.float32)
     bv = jnp.einsum("...ck,...kd->...cd", b, v32)           # (..., c, d_v)
-    out = jnp.einsum("...qc,...cd->...qd", f, jnp.matmul(u.astype(jnp.float32), bv))
+    ubv = jnp.matmul(u.astype(jnp.float32), bv, precision=CORE_PRECISION)
+    out = jnp.einsum("...qc,...cd->...qd", f, ubv)
     n_q, n_k = q.shape[-2], k.shape[-2]
     if cfg.include_shift_identity and n_q <= n_k:
         # + delta_ss * I_n maps to + delta_ss * V. Under the decode

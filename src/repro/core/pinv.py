@@ -15,6 +15,12 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+# Matmul precision of the c x c core (pinv, shift, U, U @ BV). The core is
+# tiny, and the Newton-Schulz iteration amplifies rounding: at the TPU's
+# default precision (f32 matmuls rounded to bf16 passes) the core's error
+# dominates the attention gradients. On the CPU this is the default anyway.
+CORE_PRECISION = jax.lax.Precision.HIGHEST
+
 
 def iterative_pinv(a: jnp.ndarray, num_iters: int = 6) -> jnp.ndarray:
     """Approximate pseudoinverse of ``a`` (..., c, c) via paper eq. (11)."""
@@ -29,11 +35,11 @@ def iterative_pinv(a: jnp.ndarray, num_iters: int = 6) -> jnp.ndarray:
     z0 = jnp.swapaxes(a32, -1, -2) / jnp.maximum(norm_1 * norm_inf, 1e-30)
 
     def body(_, z):
-        az = jnp.matmul(a32, z)
+        az = jnp.matmul(a32, z, precision=CORE_PRECISION)
         inner = 7.0 * eye - az
-        inner = 15.0 * eye - jnp.matmul(az, inner)
-        inner = 13.0 * eye - jnp.matmul(az, inner)
-        return 0.25 * jnp.matmul(z, inner)
+        inner = 15.0 * eye - jnp.matmul(az, inner, precision=CORE_PRECISION)
+        inner = 13.0 * eye - jnp.matmul(az, inner, precision=CORE_PRECISION)
+        return 0.25 * jnp.matmul(z, inner, precision=CORE_PRECISION)
 
     z = jax.lax.fori_loop(0, num_iters, body, z0)
     return z.astype(a.dtype)

@@ -24,7 +24,7 @@ from typing import NamedTuple
 
 import jax.numpy as jnp
 
-from repro.core.pinv import iterative_pinv, svd_pinv
+from repro.core.pinv import CORE_PRECISION, iterative_pinv, svd_pinv
 
 
 class SSCore(NamedTuple):
@@ -76,10 +76,12 @@ def ss_core(
         delta = tail / denom
     elif method == "iterative":
         z = iterative_pinv(a32, num_iters=pinv_iters).astype(dtype)
-        az = jnp.matmul(a32, z)
+        az = jnp.matmul(a32, z, precision=CORE_PRECISION)
         soft_rank = _trace(az)
         # tr(A^+ A^2) = tr(Z A A); numerator is the un-captured spectrum mass.
-        tail = _trace(a32) - _trace(jnp.matmul(az, a32))
+        tail = _trace(a32) - _trace(
+            jnp.matmul(az, a32, precision=CORE_PRECISION)
+        )
         denom = jnp.maximum(c - soft_rank, 1e-2)
         delta = jnp.maximum(tail, 0.0) / denom
     else:
@@ -88,5 +90,6 @@ def ss_core(
     if not use_shift:
         delta = jnp.zeros_like(delta)
     delta = delta[..., None, None]
-    u = jnp.matmul(z, jnp.eye(c, dtype=dtype) - delta * z)
+    u = jnp.matmul(z, jnp.eye(c, dtype=dtype) - delta * z,
+                   precision=CORE_PRECISION)
     return SSCore(u=u.astype(a_s.dtype), delta=delta.astype(a_s.dtype), z=z.astype(a_s.dtype))

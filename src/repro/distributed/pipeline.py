@@ -26,11 +26,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:  # jax >= 0.4.35
-    from jax.shard_map import shard_map
-except ImportError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map
-
 
 def stack_stages(layer_params_list: list, num_stages: int):
     """[L layer pytrees] -> pytree with leading (num_stages, L/num_stages)."""
@@ -101,12 +96,12 @@ def make_pipeline_forward(
             jax.tree.map(lambda _: P(axis), stage_params),
             P(),
         )
-        fn = shard_map(
+        fn = jax.shard_map(
             shard_body,
             mesh=mesh,
             in_specs=in_specs,
             out_specs=P(),
-            check_rep=False,
+            check_vma=False,
         )
         return fn(stage_params, microbatches)
 

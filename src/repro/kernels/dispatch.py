@@ -22,7 +22,8 @@ The measured-autotune mode times real candidate executions (jnp reference
 vs fused kernels across the (block_n, block_c) grid — ``block_c`` tiles the
 B-side kernel's landmark rows, see kernels/ss_attention.py) on synthetic
 data of the exact shape and persists winners to a JSON cache
-(``REPRO_AUTOTUNE_CACHE`` or ``~/.cache/repro/ss_autotune.json``) so
+(``REPRO_AUTOTUNE_CACHE`` or ``<repo>/.autotune/ss_autotune.json``, inside
+the checkout, so what compiles depends only on the checkout) so
 subsequent processes skip the measurement. ``n`` is bucketed to the next
 power of two to keep the cache dense across nearby sequence lengths.
 
@@ -50,7 +51,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.attention import SSConfig, spectral_shift_attention
-from repro.telemetry.metrics import NullRegistry
+from repro.runtime import REPO_ROOT
+from repro.telemetry.metrics import RegistrySlot
 
 _IMPLS = ("fused", "jnp", "interpret", "sharded", "paged")
 _FAMILIES = ("self", "decode")
@@ -59,21 +61,20 @@ _FAMILIES = ("self", "decode")
 # resolution happens at trace time on hot paths, and with telemetry off the
 # counters must cost nothing. ServeEngine/Trainer install their shared
 # registry via set_metrics() when ServeConfig.telemetry is enabled.
-_METRICS = NullRegistry()
+_METRICS = RegistrySlot()
 
 
 def set_metrics(registry) -> None:
     """Install a metrics registry for plan-resolution counters (process-
-    wide, like the plan registry itself). Pass ``NullRegistry()`` to
-    detach."""
-    global _METRICS
-    _METRICS = registry
+    wide, like the plan registry itself; held weakly, see
+    ``RegistrySlot``). Pass ``None`` to detach."""
+    _METRICS.set(registry)
 
 
 def _count_resolution(outcome: str) -> None:
     # outcome: memory|disk (cache tier hits), miss_sweep (measured
     # autotune ran), miss_heuristic (backend default used)
-    _METRICS.counter(
+    _METRICS.get().counter(
         "autotune_plan_resolutions_total",
         help="get_plan outcomes by resolution tier",
         labels=("outcome",),
@@ -183,7 +184,7 @@ def cache_path() -> str:
         return _CACHE_OVERRIDE
     return os.environ.get(
         "REPRO_AUTOTUNE_CACHE",
-        os.path.join(os.path.expanduser("~"), ".cache", "repro", "ss_autotune.json"),
+        os.path.join(REPO_ROOT, ".autotune", "ss_autotune.json"),
     )
 
 
@@ -369,7 +370,7 @@ def autotune(
     accumulators for re-streaming K/V per landmark tile."""
     from repro.kernels.ops import ss_attention_fused
 
-    _METRICS.counter(
+    _METRICS.get().counter(
         "autotune_sweeps_total", help="measured autotune sweeps run",
         labels=("family",),
     ).labels(family="self").inc()
@@ -408,7 +409,11 @@ def autotune(
                 try:
                     t = _time_call(fn, q, k, v, reps=reps)
                 except Exception:
-                    continue  # candidate doesn't lower on this backend/shape
+                    # the interpreter may reject a candidate geometry; a
+                    # compiled kernel that fails to lower is a bug
+                    if not interpret:
+                        raise
+                    continue
                 results.append((
                     t,
                     Plan(impl=fused_impl, block_n=block, block_c=bc_c,
@@ -467,7 +472,7 @@ def autotune_decode(
     from repro.serve.decode_state import recompute_stats
     from repro.serve.paged import bucket_view_slots
 
-    _METRICS.counter(
+    _METRICS.get().counter(
         "autotune_sweeps_total", help="measured autotune sweeps run",
         labels=("family",),
     ).labels(family="decode").inc()
@@ -519,7 +524,9 @@ def autotune_decode(
 
                     t += _time_call(jax.jit(fn), q, k_pool, v_pool, reps=reps)
             except Exception:
-                continue  # candidate doesn't lower on this backend/shape
+                if not interpret:  # as in autotune()
+                    raise
+                continue
             results.append((
                 t,
                 Plan(impl="paged", block_n=min(512, n), block_table=bt,

@@ -46,6 +46,7 @@ from jax.ad_checkpoint import checkpoint_name
 
 from repro.core.attention import SSConfig, _softmax, full_attention
 from repro.core.landmarks import masked_segment_means, segment_means
+from repro.core.pinv import CORE_PRECISION
 from repro.core.spectral_shift import ss_core
 from repro.kernels.ss_attention import landmark_summary, query_side
 from repro.kernels.ss_attention_bwd import landmark_summary_bwd, query_side_bwd
@@ -185,6 +186,7 @@ def ss_core_factors(q_l, k_l, cfg: SSConfig, scale: float, n_k):
             "...cd,...ed->...ce",
             q_l.astype(jnp.float32),
             k_l.astype(jnp.float32),
+            precision=CORE_PRECISION,
         )
         * scale,
         a_mask,
@@ -204,11 +206,13 @@ def ss_core_factors(q_l, k_l, cfg: SSConfig, scale: float, n_k):
                 core.z,
                 jnp.eye(c_count, dtype=core.z.dtype)
                 - (core.delta * (c_count / n_k)) * core.z,
+                precision=CORE_PRECISION,
             ),
         )
     if cfg.variant == "eq10_literal":
         u = jnp.matmul(
-            core.z, jnp.eye(c_count, dtype=a.dtype) - core.delta * a
+            core.z, jnp.eye(c_count, dtype=a.dtype) - core.delta * a,
+            precision=CORE_PRECISION,
         )
     else:
         u = core.u
@@ -316,9 +320,9 @@ def ss_attention_fused(
         (scale, block_n, block_c, cfg.causal, interpret), q_l, kf, vf,
         kv_valid,
     )  # (b, c, dv)
-    m_mat = jnp.matmul(u.astype(jnp.float32), bv.astype(jnp.float32)).astype(
-        v.dtype
-    )
+    m_mat = jnp.matmul(
+        u.astype(jnp.float32), bv.astype(jnp.float32), precision=CORE_PRECISION
+    ).astype(v.dtype)
     if cfg.include_shift_identity and n <= n_k:
         # + delta_ss I_n -> + delta_ss * V on the query-aligned rows of V
         # (decode convention: queries are the last n positions of the

@@ -23,7 +23,7 @@ body: the forward saves the **global** (BV, m, l) statistics (tagged
 ``ss_bv``/``ss_stats`` so ``remat="ss_stats"`` keeps working under SP), and
 the backward runs the existing flash-backward kernels per shard against
 those global stats — reconstruction is exact. Collective accounting under
-``check_rep=False`` (where psum transposes to psum): the B-side backward
+``check_vma=False`` (where psum transposes to psum): the B-side backward
 psums the per-shard cotangents of the replicated BV* once, and every
 cotangent of a replicated *input* (dQ~, dK~, dM, ddelta) is returned as the
 shard's local partial — the transpose of the psum that replicated the
@@ -50,13 +50,9 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:  # jax >= 0.5 moved shard_map out of experimental
-    from jax.shard_map import shard_map
-except ImportError:  # pragma: no cover - version-dependent import
-    from jax.experimental.shard_map import shard_map
-
 from repro.core.attention import SSConfig
 from repro.core.landmarks import onehot_segment_sums, segment_counts
+from repro.core.pinv import CORE_PRECISION
 from repro.kernels.ops import _float0_like, flash_rescale, ss_core_factors
 from repro.kernels.ss_attention import landmark_summary, query_side
 from repro.kernels.ss_attention_bwd import landmark_summary_bwd, query_side_bwd
@@ -114,7 +110,7 @@ def _landmark_summary_sp_bwd(meta, res, g):
     # Per-shard backward against the GLOBAL stats: P = exp(s - m*) / l* is
     # the exact global softmax factor restricted to local key columns, so
     # dK/dV are shard-complete and dQ~ is the shard's LOCAL partial. No
-    # psum on dQ~: under ``check_rep=False`` the transpose of the psum that
+    # psum on dQ~: under ``check_vma=False`` the transpose of the psum that
     # replicated q_l is itself a psum, which accumulates the partials —
     # reducing here as well would double count.
     dq_l, dk, dv = landmark_summary_bwd(
@@ -270,7 +266,8 @@ def ss_attention_fused_sharded(
 
         bv = _landmark_summary_sp(meta, q_l, k_loc, v_loc, off)  # (b, c, dv)
         m_mat = jnp.matmul(
-            u.astype(jnp.float32), bv.astype(jnp.float32)
+            u.astype(jnp.float32), bv.astype(jnp.float32),
+            precision=CORE_PRECISION,
         ).astype(v_loc.dtype)
         if cfg.include_shift_identity:
             delta = delta_core.astype(jnp.float32)
@@ -280,9 +277,9 @@ def ss_attention_fused_sharded(
             v_q = jnp.zeros_like(v_loc)
         return _query_side_sp(meta, q_loc, k_l, m_mat, v_q, delta, off)
 
-    out = shard_map(
+    out = jax.shard_map(
         body, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
-        check_rep=False,
+        check_vma=False,
     )(qf, kf, vf)
     if n_pad:
         out = out[:, :n]

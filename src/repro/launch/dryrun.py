@@ -311,8 +311,6 @@ def _lower_and_stats(cfg, shape, mesh, overrides, tcfg=None) -> dict:
         result["compile_s"] = round(time.time() - t1, 2)
 
         cost = compiled.cost_analysis() or {}
-        if isinstance(cost, (list, tuple)):  # older jax: one dict per program
-            cost = cost[0] if cost else {}
         result["flops_total"] = float(cost.get("flops", 0.0))
         result["hlo_bytes_accessed"] = float(cost.get("bytes accessed", 0.0))
         try:

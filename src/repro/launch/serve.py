@@ -20,6 +20,7 @@ from repro.configs.base import reduced
 from repro.configs.registry import ARCH_IDS, get_config
 from repro.models.model import model_specs
 from repro.models.params import init_params
+from repro.runtime import enable_compile_cache
 from repro.serve.engine import Request, ServeEngine
 
 
@@ -34,6 +35,7 @@ def main(argv=None):
     ap.add_argument("--temperature", type=float, default=0.0)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = reduced(get_config(args.arch))
     if cfg.family == "audio":

@@ -23,6 +23,7 @@ from repro.configs.base import SHAPE_PRESETS, ShapeConfig, TrainConfig, reduced
 from repro.configs.registry import ARCH_IDS, get_config
 from repro.distributed.fault_tolerance import FailureInjector
 from repro.launch.mesh import make_local_mesh, make_production_mesh
+from repro.runtime import enable_compile_cache
 from repro.train.trainer import Trainer
 
 
@@ -47,6 +48,7 @@ def main(argv=None):
                     help="inject a simulated host failure at this step")
     ap.add_argument("--metrics-out", default="")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     logging.basicConfig(level=logging.INFO, format="%(asctime)s %(message)s")
     cfg = get_config(args.arch)

@@ -60,12 +60,13 @@ def _core_attention(cfg: ModelConfig, impl: str, q, k, v, *, causal: bool):
         # shard_map context-parallel driver (kernels/sharded.py) — the key
         # carries seq_shards, so context-parallel cells keep the fused path.
         from repro.kernels.dispatch import dispatch_ss_attention
+        from repro.runtime import interpret_kernels
 
         return dispatch_ss_attention(
             q, k, v, ss_config_from(cfg, causal=causal),
             backend=cfg.attention_backend,
             autotune_enabled=cfg.autotune,
-            interpret=cfg.kernels_interpret,
+            interpret=interpret_kernels(),
         )
     if impl in ("spectral_shift", "nystrom"):
         ss = ss_config_from(cfg, causal=causal)

@@ -119,10 +119,6 @@ def moe_forward_ep(p: dict, cfg: ModelConfig, x: jnp.ndarray):
     """
     from repro.distributed.sharding import _mesh, spec_for
 
-    try:  # jax >= 0.4.35
-        from jax.shard_map import shard_map
-    except ImportError:  # pragma: no cover
-        from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     mesh = _mesh()
@@ -246,12 +242,12 @@ def moe_forward_ep(p: dict, cfg: ModelConfig, x: jnp.ndarray):
             y = y + mlp_forward(shared, xt, "swiglu")
         return y.reshape(b_loc, s, d), aux
 
-    fn = shard_map(
+    fn = jax.shard_map(
         inner,
         mesh=mesh,
         in_specs=(x_spec, P(), w_spec, w_spec, w_spec, shared_spec),
         out_specs=(x_spec, P()),
-        check_rep=False,
+        check_vma=False,
     )
     out, aux = fn(
         x, p["router"], p["w_gate"], p["w_up"], p["w_down"],

@@ -43,8 +43,7 @@ def init_params(specs, key: jax.Array, dtype=jnp.float32):
     """Materialize real parameters. Each leaf gets an independent stream
     derived from its tree path, so adding parameters never reshuffles
     existing initializations."""
-    # jax.tree.flatten_with_path only exists on newer jax; use tree_util.
-    paths_and_specs, treedef = jax.tree_util.tree_flatten_with_path(
+    paths_and_specs, treedef = jax.tree.flatten_with_path(
         specs, is_leaf=_is_spec
     )
     leaves = []
@@ -57,7 +56,10 @@ def init_params(specs, key: jax.Array, dtype=jnp.float32):
         else:
             digest = hashlib.md5(jax.tree_util.keystr(path).encode()).digest()
             sub = jax.random.fold_in(key, int.from_bytes(digest[:4], "little"))
-            arr = jax.random.normal(sub, spec.shape, jnp.float32)
+            # Drawn in the target dtype: a float32 draw of a large bf16
+            # leaf would double its peak footprint. float32 streams are
+            # unchanged by this.
+            arr = jax.random.normal(sub, spec.shape, pdt)
             leaves.append((arr * _fan_in_scale(spec)).astype(pdt))
     return jax.tree.unflatten(treedef, leaves)
 

@@ -56,7 +56,6 @@ def compressed_psum(x: jnp.ndarray, axis_name: str, residual=None):
 def make_compressed_grad_allreduce(mesh, axis_name: str = "data"):
     """Returns f(grads_tree, residual_tree) -> (reduced_tree, new_residuals),
     running the quantized all-reduce via shard_map over ``axis_name``."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     def _reduce(grads, residuals):
@@ -71,9 +70,9 @@ def make_compressed_grad_allreduce(mesh, axis_name: str = "data"):
             return reduced, new_res
 
         spec = jax.tree.map(lambda _: P(), grads)
-        return shard_map(
+        return jax.shard_map(
             inner, mesh=mesh, in_specs=(spec, spec), out_specs=(spec, spec),
-            check_rep=False,
+            check_vma=False,
         )(grads, residuals)
 
     return _reduce

@@ -48,6 +48,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
+from repro.core.pinv import CORE_PRECISION
 from repro.core.spectral_shift import ss_core
 from repro.models.layers import (
     apply_rotary,
@@ -110,7 +111,9 @@ def ss_decode_attention(
     )  # (B,H,1,c)
     a_mask = valid[None, None, :, None] & valid[None, None, None, :]
     a_raw = _masked_softmax(
-        jnp.einsum("bhcd,bhed->bhce", q_l, k_l) * scale, a_mask
+        jnp.einsum("bhcd,bhed->bhce", q_l, k_l, precision=CORE_PRECISION)
+        * scale,
+        a_mask,
     )
     eye = jnp.eye(c, dtype=jnp.float32)
     a = jnp.where(a_mask, a_raw, eye)  # invalid block pinned to identity
@@ -125,9 +128,8 @@ def ss_decode_attention(
         use_shift=cfg.include_shift_identity,
     )
     bv = jnp.einsum("bhcs,bhsd->bhcd", b_mat, v_cache.astype(jnp.float32))
-    out = jnp.einsum(
-        "bhqc,bhcd->bhqd", f, jnp.einsum("bhce,bhed->bhcd", core.u, bv)
-    )
+    ubv = jnp.einsum("bhce,bhed->bhcd", core.u, bv, precision=CORE_PRECISION)
+    out = jnp.einsum("bhqc,bhcd->bhqd", f, ubv)
     if cfg.include_shift_identity:
         v_new = jnp.take_along_axis(
             v_cache, jnp.broadcast_to(

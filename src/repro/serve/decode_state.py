@@ -53,6 +53,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.core.landmarks import onehot_segment_sums, segment_counts
+from repro.core.pinv import CORE_PRECISION
 from repro.core.spectral_shift import ss_core
 from repro.kernels.ops import flash_merge
 
@@ -277,7 +278,9 @@ def ss_decode_attention_streaming(
     )  # (B, H, 1, c)
     a_mask = valid[None, None, :, None] & valid[None, None, None, :]
     a_raw = masked_softmax(
-        jnp.einsum("bhcd,bhed->bhce", q_l, k_l) * scale, a_mask
+        jnp.einsum("bhcd,bhed->bhce", q_l, k_l, precision=CORE_PRECISION)
+        * scale,
+        a_mask,
     )
     eye = jnp.eye(c, dtype=jnp.float32)
     a = jnp.where(a_mask, a_raw, eye)  # invalid block pinned to identity
@@ -313,9 +316,8 @@ def ss_decode_attention_streaming(
         acc = jnp.where(hit, acc_a, acc)
 
     bv = acc / jnp.maximum(l, 1e-30)                      # (B, H, c, dv)
-    out = jnp.einsum(
-        "bhqc,bhcd->bhqd", f, jnp.einsum("bhce,bhed->bhcd", core.u, bv)
-    )
+    ubv = jnp.einsum("bhce,bhed->bhcd", core.u, bv, precision=CORE_PRECISION)
+    out = jnp.einsum("bhqc,bhcd->bhqd", f, ubv)
     if cfg.include_shift_identity:
         out = out + core.delta * v_new[:, :, None, :].astype(jnp.float32)
     return out.astype(q.dtype), (m, l, acc)

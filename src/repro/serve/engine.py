@@ -83,6 +83,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ModelConfig, ServeConfig
+from repro.runtime import interpret_kernels
 from repro.serve.chaos import ChaosInjector, EngineStalled, FaultPlan
 from repro.serve.decode import decode_step
 from repro.serve.paged import BlockAllocator, PagedKVCache
@@ -309,22 +310,8 @@ class ServeEngine:
         self.decode_impl = (
             "paged" if serve.decode_impl == "paged" and paged_ok else "gather"
         )
-        # landmark horizon pinned to max_seq regardless of view length
-        step = functools.partial(
-            decode_step, self.params, cfg, seq_max=self.max_seq
-        )
         # whole decode tick (read -> step -> commit) as one XLA program
-        if self.decode_impl == "paged":
-            pstep = functools.partial(
-                step, paged_meta=(serve.block_size, cfg.kernels_interpret)
-            )
-            self._fused_step = self.kv.make_paged_step(
-                lambda cache, tokens, table: pstep(
-                    cache, tokens, paged_table=table
-                )
-            )
-        else:
-            self._fused_step = self.kv.make_fused_step(jax.vmap(step))
+        self._fused_step = self._make_decode_step(cfg)
         self.batched = serve.batched_prefill and prefill_supported(cfg)
 
         # decode_streaming="frozen": the active landmark row streams with a
@@ -449,10 +436,10 @@ class ServeEngine:
 
             self._chunk_step = self.kv.make_chunk_step(
                 make_chunk_prefill_fn(
-                    params, cfg, seq_max=self.max_seq,
+                    cfg, seq_max=self.max_seq,
                     stats_impl=serve.prefill_impl, block_n=prefill_block,
                 ),
-                self._chunk,
+                self._chunk, params,
             )
         # bucket rounded up to a block multiple so prefill writes whole blocks
         b = serve.prefill_bucket
@@ -876,6 +863,26 @@ class ServeEngine:
             out[group] = host[group]
         return out
 
+    def _make_decode_step(self, cfg: ModelConfig):
+        """The decode-tick program on this engine's route; the landmark
+        horizon is pinned to max_seq regardless of view length."""
+        step = functools.partial(decode_step, cfg=cfg, seq_max=self.max_seq)
+        if self.decode_impl == "paged":
+            meta = (self.serve.block_size, interpret_kernels())
+            return self.kv.make_paged_step(
+                lambda params, cache, tokens, table: step(
+                    params, cache=cache, tokens=tokens, paged_table=table,
+                    paged_meta=meta,
+                ),
+                self.params,
+            )
+        return self.kv.make_fused_step(
+            lambda params, cache, tokens: step(
+                params, cache=cache, tokens=tokens
+            ),
+            self.params,
+        )
+
     def _ensure_exact_step(self) -> None:
         """Build the exact-mode decode program for demoted lanes. The
         storage layout is shared (exact and frozen stream the same (m, l,
@@ -883,22 +890,9 @@ class ServeEngine:
         drifting it), so demoted and normal lanes ride the same pools."""
         if self._exact_step is not None:
             return
-        cfg_e = dataclasses.replace(self.cfg, decode_streaming="exact")
-        step = functools.partial(
-            decode_step, self.params, cfg_e, seq_max=self.max_seq
+        fn = self._make_decode_step(
+            dataclasses.replace(self.cfg, decode_streaming="exact")
         )
-        if self.decode_impl == "paged":
-            pstep = functools.partial(
-                step,
-                paged_meta=(self.serve.block_size, cfg_e.kernels_interpret),
-            )
-            fn = self.kv.make_paged_step(
-                lambda cache, tokens, table: pstep(
-                    cache, tokens, paged_table=table
-                )
-            )
-        else:
-            fn = self.kv.make_fused_step(jax.vmap(step))
         if self._acct is not None:
             fn = self._acct.wrap(fn, "decode_exact")
         self._exact_step = fn

@@ -680,7 +680,7 @@ class PagedKVCache:
                 split[(*pre, slice(0, nb))]
             )
 
-    def make_fused_step(self, vmapped_decode_step):
+    def make_fused_step(self, decode_step_fn, params):
         """One jitted XLA program for the whole decode tick:
         gather lane views from the pool -> batched decode step -> commit
         (dense leaves masked to active lanes; the touched K/V block of each
@@ -693,6 +693,11 @@ class PagedKVCache:
         decode step's ``seq_max`` keeps landmark segmentation pinned to the
         full horizon regardless of view length.
 
+        ``decode_step_fn(params, cache, tokens) -> (logits, new_cache)`` is
+        the per-lane step, vmapped here over lanes with ``params`` shared.
+        ``params`` enter the program as an argument, never as captured
+        constants (XLA would embed every weight in the program).
+
         Returns ``fn(storage, tables, tokens, positions, active,
         n_view_blocks) -> (logits, new_storage)``; one XLA program compiles
         per distinct ``n_view_blocks``; the engine swaps its storage list
@@ -700,15 +705,16 @@ class PagedKVCache:
         infos, treedef = self.infos, self.treedef
         paged, bs = self.paged, self.block_size
         n_lanes = self.max_lanes
+        vstep = jax.vmap(decode_step_fn, in_axes=(None, 0, 0))
 
-        def fused(storage, tables, tokens, positions, active):
+        def fused(storage, params, tables, tokens, positions, active):
             views = [
                 arr if (not paged or info.seq_axis is None)
                 else self._gather_leaf(arr, info, tables)
                 for arr, info in zip(storage, infos)
             ]
             cache = jax.tree_util.tree_unflatten(treedef, views)
-            logits, new_cache = vmapped_decode_step(cache, tokens)
+            logits, new_cache = vstep(params, cache, tokens)
             new_leaves = jax.tree_util.tree_leaves(new_cache)
             out = []
             for arr, new, info in zip(storage, new_leaves, infos):
@@ -742,12 +748,12 @@ class PagedKVCache:
         def call(storage, tables, tokens, positions, active, n_view_blocks):
             if self.paged:
                 tables = tables[:, :n_view_blocks]
-            return jitted(storage, tables, tokens, positions, active)
+            return jitted(storage, params, tables, tokens, positions, active)
 
         call._jitted = jitted  # jit-cache probe for telemetry/accounting.py
         return call
 
-    def make_paged_step(self, decode_step_fn):
+    def make_paged_step(self, decode_step_fn, params):
         """One jitted XLA program for the *gather-free* decode tick
         (``ServeConfig.decode_impl="paged"``): pool leaves are broadcast
         unbatched through the lane vmap, the per-lane block table rides
@@ -760,10 +766,12 @@ class PagedKVCache:
         touches the dense stats leaves plus exactly one pool block per
         lane.
 
-        ``decode_step_fn(cache, tokens, table) -> (logits, new_cache)``
-        must be the paged-mode decode step (``serve/decode.py`` with
-        ``paged_meta`` set): it never writes pool leaves and returns seq
-        leaves with a length-1 seq axis holding the new token.
+        ``decode_step_fn(params, cache, tokens, table) -> (logits,
+        new_cache)`` must be the paged-mode decode step (``serve/decode.py``
+        with ``paged_meta`` set): it never writes pool leaves and returns
+        seq leaves with a length-1 seq axis holding the new token.
+        ``params`` enter the program as an argument, as in
+        ``make_fused_step``.
 
         Returns ``fn(storage, tables, tokens, positions, active,
         n_view_blocks) -> (logits, new_storage)``; like ``make_fused_step``
@@ -780,11 +788,11 @@ class PagedKVCache:
         cache_axes = jax.tree_util.tree_unflatten(
             treedef, [None if i.seq_axis is not None else 0 for i in infos]
         )
-        vstep = jax.vmap(decode_step_fn, in_axes=(cache_axes, 0, 0))
+        vstep = jax.vmap(decode_step_fn, in_axes=(None, cache_axes, 0, 0))
 
-        def fused(storage, tables, tokens, positions, active):
+        def fused(storage, params, tables, tokens, positions, active):
             cache = jax.tree_util.tree_unflatten(treedef, storage)
-            logits, new_cache = vstep(cache, tokens, tables)
+            logits, new_cache = vstep(params, cache, tokens, tables)
             new_leaves = jax.tree_util.tree_leaves(new_cache)
             ids = tables[jnp.arange(n_lanes), positions // bs]
             # inactive lanes dump into the zero block, re-zeroed below
@@ -813,18 +821,19 @@ class PagedKVCache:
         jitted = jax.jit(fused, donate_argnums=(0,))
 
         def call(storage, tables, tokens, positions, active, n_view_blocks):
-            return jitted(storage, tables[:, :n_view_blocks], tokens,
+            return jitted(storage, params, tables[:, :n_view_blocks], tokens,
                           positions, active)
 
         call._jitted = jitted  # jit-cache probe for telemetry/accounting.py
         return call
 
-    def make_chunk_step(self, chunk_fn, chunk_pad: int):
+    def make_chunk_step(self, chunk_fn, chunk_pad: int, params):
         """One jitted XLA program for a chunked-prefill step of ONE lane:
         gather the lane's committed-prefix view from the pool (plus its
         carried dense landmark/streaming leaves) -> run ``chunk_fn`` (a
-        ``make_chunk_prefill_fn`` closure: one fixed-size prompt chunk at
-        global positions start..start+chunk_valid-1) -> commit the chunk's
+        ``make_chunk_prefill_fn`` function of ``(params, cache, tokens,
+        start, chunk_valid)``: one fixed-size prompt chunk at global
+        positions start..start+chunk_valid-1) -> commit the chunk's
         K/V into the lane's blocks and the carried-forward dense state into
         the lane's dense slots. Pool buffers are donated, so the commit
         updates in place — a chunk step touches ``chunk_pad / block_size``
@@ -849,7 +858,8 @@ class PagedKVCache:
         cb = chunk_pad // bs
         max_seq = self.max_seq
 
-        def fused(storage, table_row, tokens, lane, start, chunk_valid):
+        def fused(storage, params, table_row, tokens, lane, start,
+                  chunk_valid):
             views = []
             for arr, info in zip(storage, infos):
                 if paged and info.seq_axis is not None:
@@ -859,7 +869,9 @@ class PagedKVCache:
                         jax.lax.dynamic_index_in_dim(arr, lane, 0, False)
                     )
             cache = jax.tree_util.tree_unflatten(treedef, views)
-            logits, new_cache = chunk_fn(cache, tokens, start, chunk_valid)
+            logits, new_cache = chunk_fn(
+                params, cache, tokens, start, chunk_valid
+            )
             new_leaves = jax.tree_util.tree_leaves(new_cache)
             out = []
             for arr, new, view, info in zip(storage, new_leaves, views, infos):
@@ -919,7 +931,8 @@ class PagedKVCache:
             else:
                 row = np.zeros((1, 1), np.int32)
             return jitted(
-                storage, jnp.asarray(row), jnp.asarray(tokens, jnp.int32),
+                storage, params, jnp.asarray(row),
+                jnp.asarray(tokens, jnp.int32),
                 jnp.asarray(lane, jnp.int32), jnp.asarray(start, jnp.int32),
                 jnp.asarray(chunk_valid, jnp.int32),
             )

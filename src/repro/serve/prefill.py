@@ -68,6 +68,7 @@ from repro.models.layers import apply_rotary, mlp_forward, rms_norm, rotary_angl
 from repro.models.model import _embed_tokens, _unembed, working_params
 from repro.models.moe import moe_forward
 from repro.models.params import ParamSpec
+from repro.runtime import interpret_kernels
 from repro.serve.decode import (
     _segment_len,
     full_decode_attention,
@@ -120,6 +121,20 @@ def _prefix_sums(oh: jnp.ndarray, x: jnp.ndarray) -> jnp.ndarray:
     return jnp.moveaxis(cum, 2, 0)
 
 
+def _window_sums(oh: jnp.ndarray, x: jnp.ndarray, per_position: bool):
+    """Landmark sums over the prompt window: per position (``_prefix_sums``,
+    read by replay attention) or, when nothing reads the per-position
+    prefixes, only the totals as a length-1 leading axis. The per-position
+    tensor is n*H*c*d floats — 1.4 GB per layer at Qwen2-7B widths and
+    n=1536, which ss_fused prefill would build only to keep its last row."""
+    if per_position:
+        return _prefix_sums(oh, x)
+    return jnp.einsum(
+        "nc,bhnd->bhcd", oh, x.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST,
+    )[None]
+
+
 def _attend_prefill(
     cfg: ModelConfig, impl: str, prefill_impl: str,
     q, k_b, v_b, q_sums, k_sums_b, scale, seq_max: int, t_mask,
@@ -153,7 +168,7 @@ def _attend_prefill(
         # exact-length windows, taking the branch above).
         return ss_attention_fused(
             q, k_b, v_b, ss_config_from(cfg, causal=False), scale=scale,
-            interpret=cfg.kernels_interpret, block_n=block_n,
+            interpret=interpret_kernels(), block_n=block_n,
             kv_valid=n_valid,
         )
     qs = jnp.moveaxis(q, 2, 0)[:, :, :, None, :]  # (n, B, H, 1, d)
@@ -198,7 +213,7 @@ def _seed_stream_stats(cfg: ModelConfig, prefill_impl: str, q_l, kb, vb,
             q_l.reshape(b * h, c, d),
             kb.reshape(b * h, n, d),
             vb.reshape(b * h, n, dv),
-            scale=scale, block_n=block_n, interpret=cfg.kernels_interpret,
+            scale=scale, block_n=block_n, interpret=interpret_kernels(),
             return_stats=True, kv_valid=n_valid,
         )
         m = m.reshape(b, h, c, 1)
@@ -231,8 +246,10 @@ def _gqa_prefill(p, cfg: ModelConfig, x, sin, cos, t_mask, oh, seq_max, impl,
     k_m = jnp.where(pad, k, 0).astype(k.dtype)
     v_m = jnp.where(pad, v, 0).astype(v.dtype)
 
-    q_sums = _prefix_sums(oh, q)          # (n, B, H, c, d)
-    k_sums = _prefix_sums(oh, k_m)        # (n, B, Hkv, c, d)
+    # ss_fused attention reads no per-position landmark prefixes
+    per_pos = not (prefill_impl == "ss_fused" and impl == "spectral_shift")
+    q_sums = _window_sums(oh, q, per_pos)      # (n|1, B, H, c, d)
+    k_sums = _window_sums(oh, k_m, per_pos)    # (n|1, B, Hkv, c, d)
     kb = _broadcast_kv(k_m, cfg.num_heads)
     vb = _broadcast_kv(v_m, cfg.num_heads)
     k_sums_b = jax.vmap(_broadcast_kv, (0, None))(k_sums, cfg.num_heads)
@@ -277,8 +294,9 @@ def _mla_prefill(p, cfg: ModelConfig, x, sin, cos, t_mask, oh, seq_max, impl,
     k_rope_m = jnp.where(pad2, k_rope, 0).astype(k_rope.dtype)
     k_eff = jnp.concatenate([c_kv_m, k_rope_m], axis=-1)  # (B, n, r+dr)
 
-    q_sums = _prefix_sums(oh, q_eff)                    # (n, B, H, c, de)
-    k_sums = _prefix_sums(oh, k_eff[:, None])[:, :, 0]  # (n, B, c, de)
+    per_pos = not (prefill_impl == "ss_fused" and impl == "spectral_shift")
+    q_sums = _window_sums(oh, q_eff, per_pos)                    # (n|1,B,H,c,de)
+    k_sums = _window_sums(oh, k_eff[:, None], per_pos)[:, :, 0]  # (n|1,B,c,de)
 
     h = cfg.num_heads
     k_eff_b = jnp.broadcast_to(
@@ -458,7 +476,7 @@ def _merge_chunk_stats(cfg: ModelConfig, stats_impl: str, carry, q_l, kb, vb,
             q_l.reshape(b * h, c, d),
             kb.reshape(b * h, n, d),
             vb.reshape(b * h, n, dv),
-            scale=scale, block_n=block_n, interpret=cfg.kernels_interpret,
+            scale=scale, block_n=block_n, interpret=interpret_kernels(),
             return_stats=True, kv_valid=chunk_valid,
         )
         m_w = m_w.reshape(b, h, c, 1)
@@ -699,12 +717,13 @@ def chunk_prefill(
     return logits, new_cache
 
 
-def make_chunk_prefill_fn(params, cfg: ModelConfig, *, seq_max: int,
+def make_chunk_prefill_fn(cfg: ModelConfig, *, seq_max: int,
                           stats_impl: str = "replay", block_n: int = 512):
-    """Chunk-prefill closure ``fn(cache, tokens, start, chunk_valid)`` for
-    ``PagedKVCache.make_chunk_step`` (which jits the fused gather ->
-    chunk -> commit program; one XLA program per bucketed view length)."""
-    def fn(cache, tokens, start, chunk_valid):
+    """Chunk-prefill function ``fn(params, cache, tokens, start,
+    chunk_valid)`` for ``PagedKVCache.make_chunk_step`` (which jits the
+    fused gather -> chunk -> commit program; one XLA program per bucketed
+    view length)."""
+    def fn(params, cache, tokens, start, chunk_valid):
         return chunk_prefill(
             params, cfg, cache, tokens, start, chunk_valid,
             seq_max=seq_max, stats_impl=stats_impl, block_n=block_n,
@@ -715,14 +734,22 @@ def make_chunk_prefill_fn(params, cfg: ModelConfig, *, seq_max: int,
 
 def make_prefill_fn(params, cfg: ModelConfig, *, seq_max: int,
                     prefill_impl: str = "replay", block_n: int = 512):
-    """Jitted prefill closure ``fn(tokens, n_valid)``; jax.jit specializes
-    one XLA program per padded prompt length — per bucket in both modes
+    """Prefill callable ``fn(tokens, n_valid)``; jax.jit specializes one
+    XLA program per padded prompt length — per bucket in both modes
     (``ss_fused`` masks the pad via ``kv_valid``), plus one exact-length
     program per degenerate <= num_landmarks prompt in ``ss_fused`` mode.
     ``block_n`` is the Pallas stream block (dispatch plan for the serve
-    shape)."""
-    fn = functools.partial(
-        batched_prefill, params, cfg, seq_max=seq_max,
-        prefill_impl=prefill_impl, block_n=block_n,
+    shape). ``params`` enter the program as an argument, never as captured
+    constants (XLA would embed every weight in the program)."""
+    jitted = jax.jit(
+        lambda p, tokens, n_valid: batched_prefill(
+            p, cfg, tokens, n_valid, seq_max=seq_max,
+            prefill_impl=prefill_impl, block_n=block_n,
+        )
     )
-    return jax.jit(fn)
+
+    def call(tokens, n_valid):
+        return jitted(params, tokens, n_valid)
+
+    call._jitted = jitted  # jit-cache probe for telemetry/accounting.py
+    return call

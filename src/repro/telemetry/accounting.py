@@ -43,9 +43,9 @@ from typing import Optional
 
 import numpy as np
 
-from repro.telemetry.metrics import NullRegistry
+from repro.telemetry.metrics import RegistrySlot
 
-_METRICS = NullRegistry()
+_METRICS = RegistrySlot()
 _LISTENER_INSTALLED = False
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
@@ -54,9 +54,9 @@ _tls = threading.local()
 
 def set_metrics(registry) -> None:
     """Point module-level accounting (the jax.monitoring listener) at a
-    live registry. Pass ``None`` to restore the null registry."""
-    global _METRICS
-    _METRICS = registry if registry is not None else NullRegistry()
+    live registry, held weakly (``RegistrySlot``). Pass ``None`` to restore
+    the null registry."""
+    _METRICS.set(registry)
 
 
 def current_program() -> str:
@@ -95,11 +95,12 @@ def install_compile_listener() -> None:
         if _COMPILE_EVENT not in event:
             return
         program = current_program()
-        _METRICS.counter(
+        registry = _METRICS.get()
+        registry.counter(
             "xla_backend_compiles_total",
             help="backend compiles observed via jax.monitoring",
             labels=("program",)).labels(program=program).inc()
-        _METRICS.histogram(
+        registry.histogram(
             "xla_backend_compile_seconds",
             help="backend compile durations via jax.monitoring",
             buckets=(0.01, 0.1, 0.5, 1.0, 5.0, 30.0, 120.0),
@@ -176,8 +177,6 @@ def compiled_cost(fn, *args, **kwargs) -> dict:
     lowering+compiling (AOT — does not execute). Returns zeros when the
     backend offers no analysis."""
     cost = fn.lower(*args, **kwargs).compile().cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
     if not isinstance(cost, dict):
         return {"flops": 0.0, "bytes": 0.0}
     return {

@@ -28,6 +28,7 @@ from __future__ import annotations
 
 import bisect
 import threading
+import weakref
 from typing import Callable, Optional, Sequence
 
 
@@ -277,3 +278,25 @@ class NullRegistry:
 
     def snapshot(self) -> dict:
         return {}
+
+
+class RegistrySlot:
+    """A process-wide place for the registry of whichever engine or trainer
+    installed one last. It holds the registry weakly: the registry's gauges
+    reach back into their owner (scheduler, engine, weights, KV pools), so
+    a strong global reference would keep a dropped engine alive on the
+    device for the rest of the process. Reads fall back to the null
+    registry once the owner is gone."""
+
+    def __init__(self):
+        self._ref = None
+
+    def set(self, registry) -> None:
+        self._ref = None if registry is None else weakref.ref(registry)
+
+    def get(self):
+        registry = self._ref() if self._ref is not None else None
+        return _NULL_REGISTRY if registry is None else registry
+
+
+_NULL_REGISTRY = NullRegistry()

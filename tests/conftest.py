@@ -33,6 +33,8 @@ def run_subprocess(script: str, num_devices: int = 8, timeout: int = 600) -> str
     that cannot run in the 1-device test process.
     """
     env = dict(os.environ)
+    # CPU rehearsal children: never contend with a parent for an accelerator.
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={num_devices}"
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
     proc = subprocess.run(

@@ -17,7 +17,7 @@ from repro.distributed.pipeline import (
     make_pipeline_forward, reference_forward, stack_stages,
 )
 
-mesh = jax.make_mesh((4,), ('pipe',))
+mesh = jax.make_mesh((4,), ('pipe',), axis_types=(jax.sharding.AxisType.Auto,))
 key = jax.random.PRNGKey(0)
 L, D, M, mb = 8, 32, 6, 4
 layers = []
@@ -49,7 +49,7 @@ cfg = reduced(get_config('qwen2-7b'))
 shape = ShapeConfig('train_4k', 128, 8, 'train')
 with tempfile.TemporaryDirectory() as d:
     tcfg = TrainConfig(checkpoint_dir=d, checkpoint_every=3, total_steps=20)
-    mesh = jax.make_mesh((4, 2), ('data', 'model'))
+    mesh = jax.make_mesh((4, 2), ('data', 'model'), axis_types=(jax.sharding.AxisType.Auto,) * 2)
     inj = FailureInjector({6: ['host0']})
     mon = HeartbeatMonitor([f'host{i}' for i in range(4)], timeout_s=600)
     tr = Trainer(cfg, tcfg, shape, mesh, injector=inj, monitor=mon)
@@ -96,7 +96,7 @@ def test_compressed_allreduce_multidevice():
 import jax, jax.numpy as jnp, numpy as np
 from repro.optim.compression import make_compressed_grad_allreduce
 
-mesh = jax.make_mesh((4,), ('data',))
+mesh = jax.make_mesh((4,), ('data',), axis_types=(jax.sharding.AxisType.Auto,))
 f = make_compressed_grad_allreduce(mesh, 'data')
 g = {'w': jnp.asarray(np.random.default_rng(0).normal(size=(64,)), jnp.float32)}
 r = {'w': jnp.zeros((64,), jnp.float32)}

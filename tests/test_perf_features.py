@@ -114,7 +114,7 @@ cfg = ModelConfig(moe=True, num_experts=8, top_k=2, moe_d_ff=32, d_model=16,
                   num_shared_experts=1, capacity_factor=100.0)
 p = init_params(moe_specs(cfg), jax.random.PRNGKey(0))
 x = jax.random.normal(jax.random.PRNGKey(1), (8, 12, 16)) * 0.5
-mesh = jax.make_mesh((4, 2), ('data', 'model'))
+mesh = jax.make_mesh((4, 2), ('data', 'model'), axis_types=(jax.sharding.AxisType.Auto,) * 2)
 ref, aux_ref = moe_forward(p, cfg, x)
 with mesh, sharding_rules(mesh):
     ep, aux_ep = jax.jit(lambda p_, x_: moe_forward_ep(p_, cfg, x_))(p, x)
@@ -142,7 +142,7 @@ cfg = ModelConfig(moe=True, num_experts=8, top_k=2, moe_d_ff=32, d_model=16,
                   capacity_factor=0.5)
 p = init_params(moe_specs(cfg), jax.random.PRNGKey(0))
 x = jax.random.normal(jax.random.PRNGKey(1), (8, 16, 16))
-mesh = jax.make_mesh((4, 2), ('data', 'model'))
+mesh = jax.make_mesh((4, 2), ('data', 'model'), axis_types=(jax.sharding.AxisType.Auto,) * 2)
 with mesh, sharding_rules(mesh):
     out, aux = jax.jit(lambda p_, x_: moe_forward_ep(p_, cfg, x_))(p, x)
 assert bool(jnp.all(jnp.isfinite(out)))

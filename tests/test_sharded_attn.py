@@ -26,7 +26,7 @@ from repro.core.attention import SSConfig, spectral_shift_attention
 from repro.kernels.ops import ss_attention_fused
 from repro.kernels.sharded import ss_attention_fused_sharded
 
-mesh = jax.make_mesh((4,), ("data",))
+mesh = jax.make_mesh((4,), ("data",), axis_types=(jax.sharding.AxisType.Auto,))
 rel = lambda a, b: float(np.max(
     np.abs(np.asarray(a, np.float32) - np.asarray(b, np.float32))
     / np.maximum(np.abs(np.asarray(b, np.float32)), 1e-3)))
@@ -70,7 +70,7 @@ import jax, jax.numpy as jnp, numpy as np
 from repro.core.attention import SSConfig, spectral_shift_attention
 from repro.kernels.sharded import ss_attention_fused_sharded
 
-mesh = jax.make_mesh((4,), ("data",))
+mesh = jax.make_mesh((4,), ("data",), axis_types=(jax.sharding.AxisType.Auto,))
 rel = lambda a, b: float(np.max(
     np.abs(np.asarray(a, np.float32) - np.asarray(b, np.float32))
     / np.maximum(np.abs(np.asarray(b, np.float32)), 1e-3)))
@@ -102,7 +102,7 @@ import jax, jax.numpy as jnp, numpy as np
 from repro.core.attention import SSConfig
 from repro.kernels.sharded import ss_attention_fused_sharded
 
-mesh = jax.make_mesh((4,), ("data",))
+mesh = jax.make_mesh((4,), ("data",), axis_types=(jax.sharding.AxisType.Auto,))
 ks = jax.random.split(jax.random.PRNGKey(2), 3)
 q = jax.random.normal(ks[0], (2, 192, 32)) * 0.5
 k = jax.random.normal(ks[1], (2, 192, 32)) * 0.5
@@ -138,7 +138,7 @@ from repro.distributed.sharding import (
 from repro.kernels import dispatch
 import dataclasses
 
-mesh = jax.make_mesh((4,), ("data",))
+mesh = jax.make_mesh((4,), ("data",), axis_types=(jax.sharding.AxisType.Auto,))
 cfg = reduced(
     get_config("qwen2-7b"),
     attention_impl="spectral_shift_fused",

@@ -5,8 +5,10 @@ CI artifact relies on."""
 from __future__ import annotations
 
 import dataclasses
+import gc
 import io
 import json
+import weakref
 
 import jax
 import jax.numpy as jnp
@@ -375,3 +377,20 @@ def test_engine_disabled_identical_and_clean(qwen):
             assert st["ttft_ticks_p99"] is not None
             assert st["latency_ticks_p90"] is not None
     assert out_on == out_off
+
+
+def test_dropped_engine_is_freed(qwen):
+    """An engine with telemetry on installs its registry process-wide (plan
+    resolution and compile counters), and that registry's gauges reach back
+    into the engine. Dropping the engine must still free it, with its
+    weights and KV pools, rather than leave it pinned by the global."""
+    cfg, params = qwen
+    serve = ServeConfig(max_lanes=2, max_seq=64, block_size=8, telemetry=True)
+    eng = ServeEngine(cfg, params, serve=serve)
+    for r in _reqs(cfg, 1, max_new=2):
+        eng.submit(r)
+    eng.run()
+    ref = weakref.ref(eng)
+    del eng
+    gc.collect()
+    assert ref() is None
