@@ -22,6 +22,7 @@ from __future__ import annotations
 
 from typing import NamedTuple
 
+import jax
 import jax.numpy as jnp
 
 from repro.core.pinv import CORE_PRECISION, iterative_pinv, svd_pinv
@@ -39,6 +40,7 @@ def _trace(x: jnp.ndarray) -> jnp.ndarray:
     return jnp.einsum("...ii->...", x)
 
 
+@jax.named_scope("ss_core")
 def ss_core(
     a_s: jnp.ndarray,
     *,
@@ -53,6 +55,10 @@ def ss_core(
     ``use_shift=False`` forces delta=0, which makes the SS model degenerate to
     the Nystrom prototype model exactly (useful for ablations/Theorem-1
     benchmarks).
+
+    Runs under ``jax.named_scope("ss_core")``: every operation of the core,
+    forward and backward, carries ``ss_core`` in its name stack, so a
+    device trace's ``tf_op`` tells the core apart.
     """
     c = a_s.shape[-1]
     dtype = jnp.promote_types(a_s.dtype, jnp.float32)

@@ -214,6 +214,7 @@ def paged_row_stats_lanes(
             jax.ShapeDtypeStruct((lanes, hkv, r, dv), jnp.float32),
         ),
         interpret=interpret,
+        name="paged_row_stats",
     )(
         jnp.asarray(table, jnp.int32),
         jnp.asarray(kv_valid, jnp.int32),

@@ -283,6 +283,7 @@ def landmark_summary(
             out_shape=jax.ShapeDtypeStruct((b, c, dv), v.dtype),
             scratch_shapes=scratch_shapes,
             interpret=interpret,
+            name="landmark_summary",
         )(*inputs)
 
     stat_spec = pl.BlockSpec((1, bc, 1), q_idx)
@@ -302,6 +303,7 @@ def landmark_summary(
         ),
         scratch_shapes=scratch_shapes,
         interpret=interpret,
+        name="landmark_summary",
     )(*inputs)
 
 
@@ -420,5 +422,6 @@ def query_side(
         out_specs=pl.BlockSpec((1, block_n, dv), lambda bi, i: (bi, i, 0)),
         out_shape=jax.ShapeDtypeStruct((b, n + n_pad, dv), q.dtype),
         interpret=interpret,
+        name="query_side",
     )(*inputs)
     return out[:, :n] if n_pad else out

@@ -193,6 +193,7 @@ def landmark_summary_bwd(
         ),
         scratch_shapes=[pltpu.VMEM((c, d), jnp.float32)],
         interpret=interpret,
+        name="landmark_summary_bwd",
     )(*inputs)
     if n_pad:
         dk, dv_out = dk[:, :n], dv_out[:, :n]
@@ -339,6 +340,7 @@ def query_side_bwd(
             pltpu.VMEM((1, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="query_side_bwd",
     )(*inputs)
     if n_pad:
         dq, dv_out = dq[:, :n], dv_out[:, :n]

@@ -459,7 +459,8 @@ class ServeEngine:
         if self.telemetry.enabled:
             acct.set_metrics(self.telemetry.metrics)
             acct.install_compile_listener()
-            self._acct = acct.XLAAccounting(self.telemetry.metrics)
+            self._acct = acct.XLAAccounting(self.telemetry.metrics,
+                                            self.telemetry.tracer)
             self._fused_step = self._acct.wrap(self._fused_step, "decode_tick")
             if self.batched:
                 self._prefill = self._acct.wrap(self._prefill, "prefill")
@@ -733,13 +734,16 @@ class ServeEngine:
         lane = self.lanes[i]
         if self._spectrum_mon is not None and lane.pos > 0:
             # Final landmark-mass concentration of the finished request —
-            # the online spectrum-decay proxy (telemetry only).
-            stats = self._lane_stream_stats(i)
-            self._spectrum_mon.observe(
-                np.stack([g[0] for g in stats]),
-                np.stack([g[1] for g in stats]),
-                min((lane.pos - 1) // self._seg + 1, self.cfg.num_landmarks),
-            )
+            # the online spectrum-decay proxy (telemetry only). It reads
+            # m and l alone, so acc stays on the device.
+            with self.telemetry.span("spectrum_probe"):
+                stats = self._lane_stream_stats(i, acc=False)
+                self._spectrum_mon.observe(
+                    np.stack([m for m, _ in stats]),
+                    np.stack([l for _, l in stats]),
+                    min((lane.pos - 1) // self._seg + 1,
+                        self.cfg.num_landmarks),
+                )
         uid = lane.req.uid
         self.finished[uid] = list(lane.generated)
         self.outcomes[uid] = "finished"
@@ -812,7 +816,8 @@ class ServeEngine:
         Lanes demoted by the numerics guard run on the lazily built
         exact-mode program as a second dispatch over the same (donated)
         storage; with no demotions this is exactly the single legacy call.
-        Returns ``[(device_logits, lanes)]`` for ``_merge_logits``."""
+        Returns ``[(device_logits, lanes)]`` for ``_sync_rows`` and
+        ``_merge_logits``."""
         tables = self.sched.tables()
         if self._demoted:
             normal = [i for i in active
@@ -847,19 +852,30 @@ class ServeEngine:
             parts.append((dev, group))
         return parts
 
-    def _merge_logits(self, parts: list[tuple]) -> Optional[np.ndarray]:
-        """Sync the dispatched decode parts to one (max_lanes, vocab) host
-        array (the single-part fast path is byte-identical to the legacy
-        sync). None when nothing decoded this tick."""
+    def _sync_rows(self, rows: list) -> list[np.ndarray]:
+        """The host side of a tick's device sync, in two spans:
+        ``device_wait`` until the programs and the slices ``rows`` of
+        their logits are done on the device, then ``logits_fetch``, the
+        copy to the host and the cast to float32."""
+        tel = self.telemetry
+        with tel.span("device_wait"):
+            rows = jax.block_until_ready(rows)
+        with tel.span("logits_fetch"):
+            return [np.asarray(r, np.float32) for r in rows]
+
+    @staticmethod
+    def _merge_logits(parts: list[tuple],
+                      hosts: list[np.ndarray]) -> Optional[np.ndarray]:
+        """The host rows of the dispatched decode parts (``_sync_rows`` of
+        their ``[:, 0, 0]`` slices) as one (max_lanes, vocab) array, each
+        lane from the part that decoded it. None when nothing decoded this
+        tick."""
         if not parts:
             return None
         if len(parts) == 1:
-            return np.asarray(parts[0][0][:, 0, 0], np.float32)
-        out = None
-        for dev, group in parts:
-            host = np.asarray(dev[:, 0, 0], np.float32)
-            if out is None:
-                out = np.zeros_like(host)
+            return hosts[0]
+        out = np.zeros_like(hosts[0])
+        for host, (_, group) in zip(hosts, parts):
             out[group] = host[group]
         return out
 
@@ -962,7 +978,7 @@ class ServeEngine:
                 self._numerics.check("decode_logits", logits)
             if self._stream_idx:
                 for i in active:
-                    for m, l, _ in self._lane_stream_stats(i):
+                    for m, l in self._lane_stream_stats(i, acc=False):
                         self._numerics.check("landmark_m", m)
                         self._numerics.check("landmark_l", l)
         if logits is None:
@@ -1143,13 +1159,15 @@ class ServeEngine:
             return
 
         # The tick is ONE donated XLA program (gather -> step -> commit), so
-        # host spans can only split dispatch from the device sync the logits
-        # transfer forces; use Tracer(annotate=True) + jax.profiler for
-        # phase-level device timing.
+        # host spans can only split dispatch from the device sync, and the
+        # sync into the wait for the device and the logits transfer; use
+        # Tracer(annotate=True) + jax.profiler for phase-level device
+        # timing.
         with tel.span("decode_dispatch", lanes=len(active)):
             parts = self._dispatch_decode(active)
         with tel.span("device_sync"):
-            logits = self._merge_logits(parts)
+            logits = self._merge_logits(parts, self._sync_rows(
+                [dev[:, 0, 0] for dev, _ in parts]))
 
         logits = self._post_decode_checks(active, logits)
 
@@ -1344,13 +1362,13 @@ class ServeEngine:
 
         # ---- ONE sync at the sample boundary -----------------------------
         with tel.span("device_sync"):
-            logits = self._merge_logits(parts)
-            firsts = [
-                (i, np.asarray(
-                    lg[0, cv - 1, : self.cfg.vocab_size], np.float32
-                ))
-                for i, lg, cv in pending_first
-            ]
+            rows = self._sync_rows(
+                [dev[:, 0, 0] for dev, _ in parts]
+                + [lg[0, cv - 1, : self.cfg.vocab_size]
+                   for _, lg, cv in pending_first])
+            logits = self._merge_logits(parts, rows[:len(parts)])
+            firsts = [(i, row) for (i, _, _), row
+                      in zip(pending_first, rows[len(parts):])]
 
         logits = self._post_decode_checks(active, logits)
 
@@ -1419,15 +1437,14 @@ class ServeEngine:
         if pre is not None:
             self._probe_rebase_drift(hits, positions, pre)
 
-    def _lane_stream_stats(self, lane: int) -> list[tuple]:
+    def _lane_stream_stats(self, lane: int, acc: bool = True) -> list[tuple]:
         """Host (m, l, acc) triples of one lane's streaming-stat leaves,
-        one per attention layer group."""
+        one per attention layer group; (m, l) pairs with ``acc=False``,
+        which leaves the largest of the three on the device."""
         s = self.kv._storage
-        return [
-            (np.asarray(s[im][lane]), np.asarray(s[il][lane]),
-             np.asarray(s[ia][lane]))
-            for im, il, ia in self._stream_idx
-        ]
+        n = 3 if acc else 2
+        return [tuple(np.asarray(s[j][lane]) for j in idx[:n])
+                for idx in self._stream_idx]
 
     def _probe_rebase_drift(self, hits, positions, pre) -> None:
         """The free-residual probe: the rebase just recomputed the boundary

@@ -40,6 +40,10 @@ working set, not ``max_lanes * max_seq``. Two decode-tick programs exist:
   commits via a single-block scatter. A ``decode_streaming="frozen"``
   tick therefore touches only the written block plus the dense stats
   leaves: O(c*d) + one block per token, independent of the horizon.
+
+Each program is named after what it does, so a device trace tells them
+apart (``jit(decode_gather)``, ``jit(decode_paged)``,
+``jit(prefill_chunk)``, ``jit(rebase)``).
 """
 from __future__ import annotations
 
@@ -707,7 +711,7 @@ class PagedKVCache:
         n_lanes = self.max_lanes
         vstep = jax.vmap(decode_step_fn, in_axes=(None, 0, 0))
 
-        def fused(storage, params, tables, tokens, positions, active):
+        def decode_gather(storage, params, tables, tokens, positions, active):
             views = [
                 arr if (not paged or info.seq_axis is None)
                 else self._gather_leaf(arr, info, tables)
@@ -743,7 +747,7 @@ class PagedKVCache:
                 out.append(pool)
             return logits, out
 
-        jitted = jax.jit(fused, donate_argnums=(0,))
+        jitted = jax.jit(decode_gather, donate_argnums=(0,))
 
         def call(storage, tables, tokens, positions, active, n_view_blocks):
             if self.paged:
@@ -790,7 +794,7 @@ class PagedKVCache:
         )
         vstep = jax.vmap(decode_step_fn, in_axes=(None, cache_axes, 0, 0))
 
-        def fused(storage, params, tables, tokens, positions, active):
+        def decode_paged(storage, params, tables, tokens, positions, active):
             cache = jax.tree_util.tree_unflatten(treedef, storage)
             logits, new_cache = vstep(params, cache, tokens, tables)
             new_leaves = jax.tree_util.tree_leaves(new_cache)
@@ -818,7 +822,7 @@ class PagedKVCache:
                 out.append(pool)
             return logits, out
 
-        jitted = jax.jit(fused, donate_argnums=(0,))
+        jitted = jax.jit(decode_paged, donate_argnums=(0,))
 
         def call(storage, tables, tokens, positions, active, n_view_blocks):
             return jitted(storage, params, tables[:, :n_view_blocks], tokens,
@@ -858,8 +862,8 @@ class PagedKVCache:
         cb = chunk_pad // bs
         max_seq = self.max_seq
 
-        def fused(storage, params, table_row, tokens, lane, start,
-                  chunk_valid):
+        def prefill_chunk(storage, params, table_row, tokens, lane, start,
+                          chunk_valid):
             views = []
             for arr, info in zip(storage, infos):
                 if paged and info.seq_axis is not None:
@@ -920,7 +924,7 @@ class PagedKVCache:
                 out.append(pool)
             return logits, out
 
-        jitted = jax.jit(fused, donate_argnums=(0,))
+        jitted = jax.jit(prefill_chunk, donate_argnums=(0,))
 
         def call(storage, table_row, tokens, lane, start, chunk_valid):
             if paged:
@@ -976,7 +980,7 @@ class PagedKVCache:
         paged = self.paged
         n_lanes = self.max_lanes
 
-        def fused(storage, tables, positions, flags):
+        def rebase(storage, tables, positions, flags):
             views = [
                 arr if (not paged or info.seq_axis is None)
                 else self._gather_leaf(arr, info, tables)
@@ -994,7 +998,7 @@ class PagedKVCache:
                     out.append(arr)
             return out
 
-        jitted = jax.jit(fused, donate_argnums=(0,))
+        jitted = jax.jit(rebase, donate_argnums=(0,))
 
         def call(storage, tables, positions, flags, n_view_blocks):
             if self.paged:

@@ -741,12 +741,13 @@ def make_prefill_fn(params, cfg: ModelConfig, *, seq_max: int,
     ``block_n`` is the Pallas stream block (dispatch plan for the serve
     shape). ``params`` enter the program as an argument, never as captured
     constants (XLA would embed every weight in the program)."""
-    jitted = jax.jit(
-        lambda p, tokens, n_valid: batched_prefill(
+    def prefill(p, tokens, n_valid):  # jit(prefill) on a device trace
+        return batched_prefill(
             p, cfg, tokens, n_valid, seq_max=seq_max,
             prefill_impl=prefill_impl, block_n=block_n,
         )
-    )
+
+    jitted = jax.jit(prefill)
 
     def call(tokens, n_valid):
         return jitted(params, tokens, n_valid)

@@ -28,6 +28,11 @@ The PR 7 observability layer adds three more members to the bundle:
   trace; populate it via :func:`stamp_provenance`;
 * the flight recorder shares the tracer's ``perf_counter`` origin so
   lifelines and host spans share one timeline.
+
+An enabled bundle also becomes the one that garbage-collector pauses are
+written to (:mod:`repro.telemetry.gc_pauses`): the
+``gc_pause_seconds_total{generation}`` counter and a ``gc`` span per full
+collection. A disabled one installs nothing.
 """
 from __future__ import annotations
 
@@ -42,6 +47,7 @@ from repro.telemetry.accounting import (  # noqa: F401
     install_compile_listener,
     tagged_program,
 )
+from repro.telemetry import gc_pauses
 from repro.telemetry.export import (  # noqa: F401
     chrome_trace,
     validate_trace,
@@ -91,6 +97,7 @@ class Telemetry:
             self.flight = FlightRecorder(
                 registry=self.metrics, origin=self.tracer._origin
             )
+            self._gc = gc_pauses.watch(self.metrics, self.tracer)
         else:
             self.metrics = NullRegistry()
             self.tracer = NullTracer()

@@ -9,7 +9,9 @@ see, each with its own detector here:
    treadmill without changing any output. :class:`XLAAccounting.wrap`
    instruments a jitted callable: each call checks the jit cache size
    before/after and increments ``xla_compiles_total{program=}`` on a miss
-   (plus ``xla_compile_seconds{program=}`` with the miss-call wall time).
+   (plus ``xla_compile_seconds{program=}`` with the miss-call wall time,
+   and a ``program_load`` span labelled ``program`` in the tracer: the
+   time to trace, compile or load from the persistent cache, and launch).
    Steady-state decode must show this counter FLAT across ticks.
 
    A second, lower-level channel: :func:`install_compile_listener` hooks
@@ -122,17 +124,16 @@ def _cache_size_fn(fn):
 
 
 class XLAAccounting:
-    """Per-program compile counters over wrapped jitted callables."""
+    """Per-program compile counters over wrapped jitted callables; each
+    miss also goes to ``tracer`` (when given) as a ``program_load``
+    span."""
 
-    def __init__(self, registry):
+    def __init__(self, registry, tracer=None):
         self._registry = registry
+        self._tracer = tracer
         self._compiles = registry.counter(
             "xla_compiles_total",
             help="jit cache misses per instrumented program",
-            labels=("program",))
-        self._calls = registry.counter(
-            "xla_program_calls_total",
-            help="calls per instrumented program",
             labels=("program",))
         self._compile_s = registry.histogram(
             "xla_compile_seconds",
@@ -142,26 +143,29 @@ class XLAAccounting:
 
     def wrap(self, fn, program: str):
         """Instrument a jitted callable (or a closure exposing
-        ``_jitted``): count calls, detect cache-size growth as a compile,
-        and tag the region so the backend-compile listener attributes
+        ``_jitted``): detect cache-size growth as a compile, time it, and
+        tag the region so the backend-compile listener attributes
         correctly. Returns ``fn`` untouched when no cache probe exists."""
         probe = _cache_size_fn(fn)
         if probe is None:
             return fn
-        calls = self._calls.labels(program=program)
         compiles = self._compiles.labels(program=program)
         compile_s = self._compile_s.labels(program=program)
+        tracer = self._tracer
 
         @functools.wraps(fn)
         def wrapped(*args, **kwargs):
-            calls.inc()
             before = probe()
             t0 = time.perf_counter()
             with tagged_program(program):
                 out = fn(*args, **kwargs)
             if probe() > before:
+                seconds = time.perf_counter() - t0
                 compiles.inc()
-                compile_s.observe(time.perf_counter() - t0)
+                compile_s.observe(seconds)
+                if tracer is not None:
+                    tracer.interval("program_load", t0, seconds,
+                                    program=program)
             return out
 
         wrapped._jitted = getattr(fn, "_jitted", fn)

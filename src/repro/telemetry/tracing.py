@@ -103,6 +103,15 @@ class Tracer:
             annotation = jax.profiler.StepTraceAnnotation(name, step_num=step)
         return _Span(self, name, {"step": step}, annotation)
 
+    def interval(self, name: str, t0: float, seconds: float,
+                 **labels) -> None:
+        """Record a span measured after the fact: ``seconds`` from ``t0``
+        on ``time.perf_counter``, nested in whatever spans this thread has
+        open (program loads, GC pauses). It reaches a profile only through
+        an annotation the caller held over the interval."""
+        depth = getattr(self._tls, "depth", 0)
+        self._record(name, t0, seconds, depth, labels or None)
+
     def _record(self, name, t0, dur, depth, labels):
         if len(self.events) >= self.max_events:
             self.dropped += 1
@@ -155,6 +164,10 @@ class NullTracer:
 
     def step_span(self, name: str, step: int):
         return _NULL_SPAN
+
+    def interval(self, name: str, t0: float, seconds: float,
+                 **labels) -> None:
+        pass
 
     def summary(self) -> dict:
         return {"events": 0, "dropped": 0}

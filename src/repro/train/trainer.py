@@ -143,9 +143,9 @@ class Trainer:
 
                 acct.set_metrics(self.telemetry.metrics)
                 acct.install_compile_listener()
-                self.jitted = acct.XLAAccounting(self.telemetry.metrics).wrap(
-                    self.jitted, "train_step"
-                )
+                self.jitted = acct.XLAAccounting(
+                    self.telemetry.metrics, self.telemetry.tracer
+                ).wrap(self.jitted, "train_step")
 
             latest = self.ckpt.latest_step() if restore else None
             if latest is not None:
@@ -261,7 +261,7 @@ class Trainer:
                     dead = self.injector.failures_at(self.step)
                     if dead:
                         self._handle_failure(dead)
-                t0 = time.time()
+                t0 = time.perf_counter()
                 with self.telemetry.step_span("train_step", self.step):
                     host_batch = self.data.batch(self.step)
                     batch = make_global_batch(host_batch, self.b_sh)
@@ -272,7 +272,7 @@ class Trainer:
                         k: float(v)
                         for k, v in metrics.items() if jnp.ndim(v) == 0
                     }
-                dt = time.time() - t0
+                dt = time.perf_counter() - t0
                 metrics["step"] = self.step
                 metrics["step_time_s"] = dt
                 self.metrics_history.append(metrics)

@@ -394,3 +394,95 @@ def test_dropped_engine_is_freed(qwen):
     del eng
     gc.collect()
     assert ref() is None
+
+
+# ==========================================================================
+# gc_pauses.py and program loads
+# ==========================================================================
+def _full_collection():
+    """One full collection and no other: automatic collection is held off
+    around it."""
+    was = gc.isenabled()
+    gc.disable()
+    try:
+        gc.collect()
+    finally:
+        if was:
+            gc.enable()
+
+
+def _gc_seconds(tel, generation=2):
+    fam = tel.metrics.snapshot().get("gc_pause_seconds_total", {})
+    return fam.get(f"generation={generation}", {}).get("value", 0.0)
+
+
+def _gc_spans(tel):
+    return [e for e in tel.tracer.events if e["name"] == "gc"]
+
+
+@pytest.mark.parametrize("annotate", [False, True])
+def test_full_collection_is_counted_and_spanned(annotate):
+    from repro.telemetry import gc_pauses
+
+    tel = Telemetry(annotate=annotate)
+    with tel.span("outer"):
+        _full_collection()
+    spans = _gc_spans(tel)
+    assert len(spans) == 1
+    assert spans[0]["depth"] == 1  # nested in the span it interrupted
+    assert _gc_seconds(tel) > 0
+    assert _gc_seconds(tel) == pytest.approx(spans[0]["dur_s"], abs=1e-8)
+    assert gc.callbacks.count(gc_pauses.HOOK) == 1
+    # a dropped bundle is not kept alive by the hook's slot
+    ref = weakref.ref(tel)
+    del tel
+    gc.collect()
+    assert ref() is None
+
+
+def test_disabled_telemetry_registers_nothing_for_gc():
+    live = Telemetry()
+    hooks = list(gc.callbacks)
+    off = Telemetry(enabled=False)
+    assert gc.callbacks == hooks
+    _full_collection()
+    assert off.metrics.snapshot() == {} and off.tracer.events == []
+    # the pauses still go to the enabled bundle
+    assert len(_gc_spans(live)) == 1 and _gc_seconds(live) > 0
+
+
+def test_two_bundles_do_not_double_count_gc():
+    older, newer = Telemetry(), Telemetry()
+    _full_collection()
+    assert len(_gc_spans(older)) + len(_gc_spans(newer)) == 1
+    assert len(_gc_spans(newer)) == 1 and _gc_seconds(older) == 0
+
+
+def test_program_load_span_on_a_jit_cache_miss_only():
+    from repro.telemetry import XLAAccounting
+
+    tel = Telemetry()
+    fn = XLAAccounting(tel.metrics, tel.tracer).wrap(
+        jax.jit(lambda x: x * 3.0), "toy")
+
+    def loads():
+        return [e for e in tel.tracer.events if e["name"] == "program_load"]
+
+    with tel.span("warm"):
+        fn(jnp.ones(4))
+    (load,) = loads()
+    assert load["labels"] == {"program": "toy"} and load["depth"] == 1
+    assert load["dur_s"] > 0
+    fn(jnp.ones(4))  # same shape: a cache hit records nothing
+    assert len(loads()) == 1
+    fn(jnp.ones(8))
+    assert len(loads()) == 2
+
+
+def test_tracer_interval_records_after_the_fact():
+    tr = Tracer()
+    tr.interval("late", tr._origin + 1.0, 0.25, kind="x")
+    (ev,) = tr.events
+    assert ev == {"name": "late", "t": 1.0, "dur_s": 0.25, "depth": 0,
+                  "labels": {"kind": "x"}}
+    NullTracer().interval("late", 0.0, 1.0)  # a no-op

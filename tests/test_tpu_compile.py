@@ -14,6 +14,8 @@ compiles (an entry written for a described chip cannot be read back here).
 """
 from __future__ import annotations
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -60,6 +62,23 @@ def _kernel_calls(fn, *args) -> int:
     """Compile ``fn`` for the argument shapes; count Mosaic kernel calls."""
     text = jax.jit(fn).lower(*args).compile().as_text()
     return text.count('custom_call_target="tpu_custom_call"')
+
+
+def _kernel_names(fn, *args) -> list[tuple[str, str]]:
+    """Compile ``fn``; for each Mosaic kernel call, in program order, its
+    HLO instruction name and the kernel name in its op metadata
+    (``.../<name>/pallas_call``, or ``.../jvp(<name>)/pallas_call`` and
+    the like under a transformation)."""
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    out = []
+    for line in text.splitlines():
+        if 'custom_call_target="tpu_custom_call"' not in line:
+            continue
+        instr = re.search(r"%([\w.\-]+) = ", line).group(1)
+        kernel = re.search(r'op_name="[^"]*/(?:\w+\()*(\w+)\)*/pallas_call"',
+                           line).group(1)
+        out.append((instr, kernel))
+    return out
 
 
 def _shape(sharding, *dims, dtype=BF16):
@@ -171,3 +190,55 @@ def test_sharded_fused_over_four_chips(topo):
 
     args = [_shape(seq, 1, HEADS, N, D) for _ in range(3)]
     assert _kernel_calls(fn, *args) >= 2  # landmark_summary + query_side
+
+
+@pytest.mark.parametrize("kernel", [
+    "landmark_summary", "query_side", "landmark_summary_bwd",
+    "query_side_bwd", "paged_row_stats"])
+def test_kernel_carries_its_name(one_chip, kernel):
+    """Each kernel's ``pallas_call`` names it: the name is in the op
+    metadata of its ``tpu_custom_call`` (and so in a device trace's
+    ``tf_op``) and names the HLO instruction, whatever line calls it."""
+    kv = _shape(one_chip, HEADS, N, D)
+    lm = _shape(one_chip, HEADS, C, D)
+    delta = _shape(one_chip, HEADS, 1, 1, dtype=jnp.float32)
+    if kernel == "landmark_summary":
+        fn = lambda q_l, k, v: landmark_summary(  # noqa: E731
+            q_l, k, v, scale=SCALE, block_n=BLOCK_N, interpret=False)
+        args, want = (lm, kv, kv), ["landmark_summary"]
+    elif kernel == "query_side":
+        fn = lambda q, k_l, m_mat, v, d: query_side(  # noqa: E731
+            q, k_l, m_mat, v, d, scale=SCALE, block_n=BLOCK_N,
+            interpret=False)
+        args, want = (kv, lm, lm, kv, delta), ["query_side"]
+    elif kernel == "landmark_summary_bwd":
+        meta = (SCALE, BLOCK_N, 0, False, False)
+        fn = jax.grad(lambda q_l, k, v: jnp.sum(landmark_summary_op(
+            meta, q_l, k, v).astype(jnp.float32)), argnums=(0, 1, 2))
+        args = (lm, kv, kv)
+        want = ["landmark_summary", "landmark_summary_bwd"]
+    elif kernel == "query_side_bwd":
+        meta = (SCALE, BLOCK_N, False, N, False)
+        fn = jax.grad(lambda q, k_l, m_mat, v, d: jnp.sum(query_side_op(
+            meta, q, k_l, m_mat, v, d).astype(jnp.float32)),
+            argnums=(0, 1, 2, 3))
+        args, want = (kv, lm, lm, kv, delta), ["query_side_bwd"]
+    else:
+        lanes, block, horizon = 8, 16, 2048
+        num_blocks = lanes * horizon // block + 1
+
+        def fn(q, k_pool, v_pool, table, kv_valid):
+            return paged_row_stats_lanes(
+                q, (k_pool,), v_pool, table, kv_valid, scale=SCALE,
+                block_size=block, interpret=False)
+
+        pool = _shape(one_chip, KV_HEADS, num_blocks, block, D)
+        args = (_shape(one_chip, lanes, KV_HEADS, HEADS // KV_HEADS, D,
+                       dtype=jnp.float32), pool, pool,
+                _shape(one_chip, lanes, horizon // block, dtype=jnp.int32),
+                _shape(one_chip, lanes, dtype=jnp.int32))
+        want = ["paged_row_stats"]
+    calls = _kernel_names(fn, *args)
+    assert sorted({kernel for _, kernel in calls}) == want
+    for instr, kernel in calls:
+        assert kernel in instr, (instr, kernel)
