@@ -5,8 +5,8 @@ tiling, segment-causal masks, online-softmax stats, dynamic key-validity
 bounds), ``ss_attention_bwd.py`` the flash-style backward kernels,
 ``ops.py`` the jitted custom-VJP wrappers, ``sharded.py`` the shard_map
 context-parallel driver (per-shard kernels + landmark-sized collectives),
-``paged_decode.py`` the gather-free serving decode kernel (scalar-prefetch
-block-table index maps over the shared KV block pools),
+``paged_decode.py`` the gather-free serving decode kernel (chunks of
+pool pages copied by DMA through the scalar-prefetched block table),
 ``dispatch.py`` the impl/block-size registry with measured autotune, and
 ``ref.py`` the pure-jnp oracles. Validated in interpret mode on CPU; TPU
 v5e is the compile target.

@@ -468,9 +468,9 @@ def autotune_decode(
     that share a shape key but differ in block size overwrite each
     other's measured winner — last tuned wins, a deliberate granularity
     trade-off, but never measure at a geometry you don't serve."""
-    from repro.kernels.paged_decode import paged_row_stats_lanes
+    from repro.kernels.paged_decode import lane_width, paged_row_stats_lanes
     from repro.serve.decode_state import recompute_stats
-    from repro.serve.paged import bucket_view_slots
+    from repro.serve.paged import bucket_view_slots, fit_lanes
 
     _METRICS.get().counter(
         "autotune_sweeps_total", help="measured autotune sweeps run",
@@ -489,6 +489,7 @@ def autotune_decode(
     table = jnp.arange(1, n_slots_full + 1, dtype=jnp.int32)
     views = sorted({max(n_slots_full // 2, 1), n_slots_full})
     scale = 1.0 / (d ** 0.5)
+    padded = [fit_lanes(x, lane_width(d)) for x in (q, k_pool, v_pool)]
 
     def time_gather(nv: int) -> float:
         tb = table[:nv]
@@ -522,7 +523,8 @@ def autotune_decode(
                             interpret=interpret,
                         )
 
-                    t += _time_call(jax.jit(fn), q, k_pool, v_pool, reps=reps)
+                    # the pools as serving stores them (lane-padded)
+                    t += _time_call(jax.jit(fn), *padded, reps=reps)
             except Exception:
                 if not interpret:  # as in autotune()
                     raise

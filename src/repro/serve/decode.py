@@ -42,6 +42,7 @@ route (the engine enforces the fallback).
 from __future__ import annotations
 
 import functools
+import itertools
 from typing import Any
 
 import jax
@@ -160,17 +161,29 @@ def _paged_merged_stats(q_g, k_pools, v_pool, k_new_g, v_new_g, paged, pos,
     """Exact softmax partials of rows ``q_g`` (hkv, R, d) over keys
     ``0..pos``: the kernel streams the pools (which hold keys 0..pos-1 —
     the tick commits the new token after the step), the current token is
-    flash-merged on top."""
+    flash-merged on top. ``k_new_g`` is the current token's key as one
+    part (hkv, d_p) per key pool; their widths split ``q_g``'s features.
+    The pools are stored lane-padded (serve/paged.py), so each part of the
+    rows is zero-padded to its pool's width and ``acc`` is cut back to
+    ``v_new_g``'s."""
     from repro.kernels.ops import flash_merge
     from repro.kernels.paged_decode import paged_row_stats
+    from repro.serve.paged import fit_lanes
 
     table, block_size, interpret = paged
+    ends = list(itertools.accumulate(k.shape[-1] for k in k_new_g))
+    q_pools = jnp.concatenate([
+        fit_lanes(x, p.shape[-1])
+        for x, p in zip(jnp.split(q_g, ends[:-1], axis=-1), k_pools)
+    ], axis=-1)
     m, l, acc = paged_row_stats(
-        q_g, k_pools, v_pool, table, pos, scale=scale,
+        q_pools, k_pools, v_pool, table, pos, scale=scale,
         block_size=block_size, interpret=interpret,
     )
+    acc = acc[..., :v_new_g.shape[-1]]
+    k_new = jnp.concatenate(k_new_g, axis=-1)
     s_new = jnp.einsum(
-        "hrd,hd->hr", q_g.astype(jnp.float32), k_new_g.astype(jnp.float32)
+        "hrd,hd->hr", q_g.astype(jnp.float32), k_new.astype(jnp.float32)
     )[..., None] * scale                                   # (hkv, R, 1)
     return flash_merge(
         m, l, acc, s_new, jnp.ones_like(s_new),
@@ -182,8 +195,8 @@ def _paged_active_stats_fn(k_pools, v_pool, k_new_g, v_new_g, paged, pos,
                            scale):
     """The ``active_stats_fn`` hook for ``ss_decode_attention_streaming``:
     one-row exact recompute through the block-table kernel. ``k_new_g`` /
-    ``v_new_g`` are the current token's key/value with RAW kv heads
-    (hkv, d) / (hkv, dv)."""
+    ``v_new_g`` are the current token's key/value with RAW kv heads: one
+    (hkv, d_p) part per key pool / (hkv, dv)."""
     hkv = v_pool.shape[0]
 
     def fn(q_act):  # (B=1, H, 1, d) active landmark means
@@ -269,7 +282,7 @@ def gqa_decode(p, cfg: ModelConfig, x, cache, pos, impl, seq_max=None,
     scale = dh**-0.5
     if paged is not None:
         k_pools, v_pool = (cache["k"][0],), cache["v"][0]  # (hkv, nb, bs, d)
-        k_new_g, v_new_g = k[0, :, 0], v[0, :, 0]          # raw kv heads
+        k_new_g, v_new_g = (k[0, :, 0],), v[0, :, 0]       # raw kv heads
     if impl == "spectral_shift":
         k_lmk = _broadcast_kv(new_cache["k_lmk"], cfg.num_heads)
         if cfg.decode_streaming == "recompute":
@@ -362,11 +375,12 @@ def mla_decode(p, cfg: ModelConfig, x, cache, pos, impl, seq_max=None,
         )[:, None]  # (B,1,S,r+dr)
         lat = new_cache["latent"][:, None]  # (B,1,S,r) as values
     else:
-        # hkv=1 pools: latent (1, nb, bs, r), rope (1, nb, bs, dr); the
-        # latent pool doubles as the value pool (absorbed MLA).
+        # hkv=1 pools: latent (1, nb, bs, r), rope (1, nb, bs, dr), each
+        # stored lane-padded; the latent pool doubles as the value pool
+        # (absorbed MLA).
         k_pools = (cache["latent"][0][None], cache["rope"][0][None])
         v_pool = k_pools[0]
-        k_new_g = k_eff_new[0][None]                        # (1, r+dr)
+        k_new_g = (c_kv[0, 0][None], k_rope[0, 0][None])  # (1, r), (1, dr)
         v_new_g = c_kv[0, 0][None]                          # (1, r)
     if impl == "spectral_shift":
         k_lmk = jnp.broadcast_to(

@@ -310,6 +310,18 @@ class ServeEngine:
         self.decode_impl = (
             "paged" if serve.decode_impl == "paged" and paged_ok else "gather"
         )
+        # Pages of each paged tick's view, every lane once: those the
+        # lane's position reaches (the kernel streams them, and ZERO_BLOCK
+        # pages to the end of the lane's last chunk) and the tail past
+        # them. Host arithmetic on positions the engine holds; no sync.
+        self._pages = None
+        if self.telemetry.enabled and self.decode_impl == "paged":
+            fam = self.telemetry.metrics.counter(
+                "paged_decode_pages_total", labels=("kind",),
+                help="paged decode view pages a tick, every lane once: "
+                     "reached by its position (streamed) or past it (tail)")
+            self._pages = (fam.labels(kind="streamed"),
+                           fam.labels(kind="tail"))
         # whole decode tick (read -> step -> commit) as one XLA program
         self._fused_step = self._make_decode_step(cfg)
         self.batched = serve.batched_prefill and prefill_supported(cfg)
@@ -831,6 +843,7 @@ class ServeEngine:
             self._ensure_exact_step()
             groups.append((self._exact_step, demoted))
         parts = []
+        idle = self.max_lanes - len(active)   # lanes no launch decodes
         for step_fn, group in groups:
             if not group:
                 continue
@@ -844,6 +857,14 @@ class ServeEngine:
             nb_view = self.kv.view_blocks_needed(
                 positions, group, quantum=self._view_quantum
             )
+            if self._pages is not None:
+                # each lane against the view of the launch that decodes
+                # it; the idle lanes against the first launch's
+                bs = self.serve.block_size
+                reached = sum(-(-int(positions[i]) // bs) for i in group)
+                self._pages[0].inc(reached)
+                self._pages[1].inc((len(group) + idle) * nb_view - reached)
+                idle = 0
             dev, new_storage = step_fn(
                 self.kv._storage, jnp.asarray(tables), jnp.asarray(tokens),
                 jnp.asarray(positions), jnp.asarray(mask), nb_view,

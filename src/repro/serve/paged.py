@@ -17,9 +17,11 @@ Two pieces:
   so a stacked-layer leaf ``(L, B, H, S, D)`` pools as
   ``(L, B, H, num_blocks, block_size, D)`` — the layer axis stays leading
   and the tree remains ``lax.scan``-compatible without any per-tick
-  transpose. Everything else (landmark running sums, streaming B-side
-  stats, SSM states, ``pos``) is small and fixed-size, so it stays dense
-  per lane exactly like the seed engine. ``write_prefill`` installs a
+  transpose. ``D`` is stored rounded up to whole 128-lane tiles
+  (``kernels.paged_decode.lane_width``, zero columns); reads cut it
+  back. Everything else (landmark running sums, streaming B-side stats,
+  SSM states, ``pos``) is small and fixed-size, so it stays dense per
+  lane exactly like the seed engine. ``write_prefill`` installs a
   batched prefill's result; ``gather_views`` assembles the lane-stacked
   dense tree for inspection/tests.
 
@@ -35,8 +37,8 @@ working set, not ``max_lanes * max_seq``. Two decode-tick programs exist:
   (``ServeConfig.decode_impl="paged"``): the decode step reads K/V
   directly from the shared pools through the block-table-aware Pallas
   kernel (``kernels/paged_decode.py`` — the lane's block table rides into
-  the kernel as a scalar-prefetch SMEM operand and selects pool blocks in
-  the index map, so no dense view ever exists), and the new token's K/V
+  the kernel as a scalar-prefetch SMEM operand that addresses the DMA of
+  each pool block, so no dense view ever exists), and the new token's K/V
   commits via a single-block scatter. A ``decode_streaming="frozen"``
   tick therefore touches only the written block plus the dense stats
   leaves: O(c*d) + one block per token, independent of the horizon.
@@ -56,6 +58,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ModelConfig, ServeConfig
+from repro.kernels.paged_decode import lane_width
 from repro.models.params import ParamSpec
 from repro.serve.kv_cache import cache_leaf_layout
 
@@ -575,6 +578,17 @@ def _leaf_infos(cfg: ModelConfig, max_seq: int) -> tuple[list[_LeafInfo], Any]:
     return [_LeafInfo(spec, j) for spec, j in leaves], treedef
 
 
+def fit_lanes(x: jnp.ndarray, width: int) -> jnp.ndarray:
+    """``x`` with its last (feature) axis zero-padded or cut to ``width``:
+    between a pool's stored width and the model's."""
+    if x.shape[-1] > width:
+        return x[..., :width]
+    if x.shape[-1] < width:
+        return jnp.pad(x, [(0, 0)] * (x.ndim - 1)
+                       + [(0, width - x.shape[-1])])
+    return x
+
+
 class PagedKVCache:
     """Block-pool device storage for one engine's decode state.
 
@@ -599,11 +613,15 @@ class PagedKVCache:
                 # Pool layout: the cache_seq axis splits IN PLACE into
                 # (num_blocks, block_size), so leading layer/batch axes stay
                 # leading (lax.scan over layers keeps working on pools).
+                # The feature axis is stored lane-padded (zero columns;
+                # MLA's 64-wide rope keys take 128): the paged decode
+                # kernel's DMAs move whole 128-lane tiles, and TPU HBM
+                # holds a narrower axis padded to the tile anyway.
                 j = info.seq_axis
                 shape = info.spec.shape
                 self._storage.append(jnp.zeros(
                     (*shape[:j], self.num_blocks, self.block_size,
-                     *shape[j + 1:]), dt,
+                     *shape[j + 1:-1], lane_width(shape[-1])), dt,
                 ))
             else:
                 self._storage.append(
@@ -630,8 +648,9 @@ class PagedKVCache:
         g = jnp.take(arr, tables, axis=j)
         g = jnp.moveaxis(g, j, 0)          # rows leading
         view_len = tables.shape[1] * self.block_size
-        return g.reshape(tables.shape[0], *shape[:j], view_len,
-                         *shape[j + 1:])
+        g = g.reshape(tables.shape[0], *shape[:j], view_len,
+                      *arr.shape[j + 2:])
+        return fit_lanes(g, shape[-1])
 
     def gather_views(self, tables: np.ndarray) -> Any:
         """tables (max_lanes, blocks_per_lane) int32, ZERO_BLOCK where
@@ -680,8 +699,9 @@ class PagedKVCache:
             )
             pre = (slice(None),) * j
             ids = jnp.asarray(table_row[:nb], jnp.int32)
-            self._storage[idx] = self._storage[idx].at[(*pre, ids)].set(
-                split[(*pre, slice(0, nb))]
+            arr = self._storage[idx]
+            self._storage[idx] = arr.at[(*pre, ids)].set(
+                fit_lanes(split[(*pre, slice(0, nb))], arr.shape[-1])
             )
 
     def make_fused_step(self, decode_step_fn, params):
@@ -738,9 +758,10 @@ class PagedKVCache:
                 # inactive lanes dump into the zero block, re-zeroed below
                 ids = jnp.where(active, ids, ZERO_BLOCK)
                 pre = (slice(None),) * j
-                pool = arr.at[(*pre, ids)].set(
-                    jnp.moveaxis(blocks, 0, j).astype(arr.dtype)
-                )
+                pool = arr.at[(*pre, ids)].set(fit_lanes(
+                    jnp.moveaxis(blocks, 0, j).astype(arr.dtype),
+                    arr.shape[-1],
+                ))
                 pool = pool.at[(*pre, ZERO_BLOCK)].set(
                     jnp.zeros_like(pool[(*pre, ZERO_BLOCK)])
                 )
@@ -763,7 +784,7 @@ class PagedKVCache:
         unbatched through the lane vmap, the per-lane block table rides
         along as a traced operand (reaching the Pallas decode kernel in
         ``kernels/paged_decode.py`` as a scalar-prefetch SMEM input that
-        selects pool blocks in the index map), and every seq-shaped cache
+        addresses the DMA of each pool block), and every seq-shaped cache
         leaf comes back from the step as the lane's NEW TOKEN only —
         committed here with a single-block scatter. No dense view of the
         horizon is ever materialized: a ``decode_streaming="frozen"`` tick
@@ -815,7 +836,8 @@ class PagedKVCache:
                 # row per leaf per lane.
                 pre = (slice(None),) * j
                 vals = jnp.moveaxis(jnp.squeeze(new, axis=1 + j), 0, j)
-                pool = arr.at[(*pre, ids, offs)].set(vals.astype(arr.dtype))
+                pool = arr.at[(*pre, ids, offs)].set(
+                    fit_lanes(vals.astype(arr.dtype), arr.shape[-1]))
                 pool = pool.at[(*pre, ZERO_BLOCK)].set(
                     jnp.zeros_like(pool[(*pre, ZERO_BLOCK)])
                 )
@@ -917,7 +939,8 @@ class PagedKVCache:
                 nvb = -(-chunk_valid // bs)  # traced ceil-div
                 ids = jnp.where(jnp.arange(cb) < nvb, ids, ZERO_BLOCK)
                 pre = (slice(None),) * j
-                pool = arr.at[(*pre, ids)].set(split.astype(arr.dtype))
+                pool = arr.at[(*pre, ids)].set(
+                    fit_lanes(split.astype(arr.dtype), arr.shape[-1]))
                 pool = pool.at[(*pre, ZERO_BLOCK)].set(
                     jnp.zeros_like(pool[(*pre, ZERO_BLOCK)])
                 )

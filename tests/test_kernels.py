@@ -485,16 +485,69 @@ class TestPagedDecodeKernel:
             q[lane][None], kv, vv, int(kv_valid[lane]) - 1, scale
         )
 
-    def test_vs_dense_recompute(self):
+    def _setup_chunked(self, kv_valid, n_slots, lanes=2, hkv=4, r=7,
+                       d=128, dv=128, bs=16, seed=41):
+        """Qwen2-7B's decode geometry scaled down (4 kv heads, 7 rows per
+        head, 16-token pages, d 128, float32 pools), where a chunk is 16
+        pages: each lane owns distinct permuted blocks for the slots its
+        ``kv_valid`` reaches and ZERO_BLOCK backs the rest of its view."""
+        from repro.serve.paged import ZERO_BLOCK
+
+        rng = np.random.default_rng(seed)
+        need = [-(-kv // bs) for kv in kv_valid]
+        nb_pool = sum(need) + 1
+        q = jnp.asarray(rng.normal(size=(lanes, hkv, r, d)), jnp.float32)
+        k_pool = jnp.asarray(
+            rng.normal(size=(hkv, nb_pool, bs, d)), jnp.float32
+        ).at[:, ZERO_BLOCK].set(0.0)
+        v_pool = jnp.asarray(
+            rng.normal(size=(hkv, nb_pool, bs, dv)), jnp.float32
+        ).at[:, ZERO_BLOCK].set(0.0)
+        blocks = rng.permutation(np.arange(1, nb_pool))
+        tables = np.full((lanes, n_slots), ZERO_BLOCK, np.int32)
+        off = 0
+        for lane, n in enumerate(need):
+            tables[lane, :n] = blocks[off:off + n]
+            off += n
+        return (q, k_pool, v_pool, jnp.asarray(tables),
+                jnp.asarray(kv_valid, jnp.int32))
+
+    # kv_valid per lane, view slots; chunks are 16 pages (256 keys) in the
+    # chunked cases
+    _CASES = {
+        "ragged_blocks": None,                     # _setup's small case
+        "slots_not_divisible": ([611, 380], 40),   # chunks of 16, 16, 8
+        "empty_beside_full": ([512, 0, 300], 32),
+        "chunk_boundary": ([256, 512], 48),
+        "all_tail_chunks": ([20, 17], 64),         # 3 of 4 chunks empty
+        "deployment_scaled": ([1000, 333, 64, 1], 64),
+    }
+
+    @pytest.mark.parametrize("case", list(_CASES))
+    def test_vs_dense_recompute(self, case):
         from repro.kernels.paged_decode import paged_row_stats_lanes
 
-        q, k_pool, v_pool, tables, kv_valid = self._setup()
+        spec = self._CASES[case]
+        if spec is None:
+            q, k_pool, v_pool, tables, kv_valid = self._setup()
+            bs = 8
+        else:
+            kvv, n_slots = spec
+            q, k_pool, v_pool, tables, kv_valid = self._setup_chunked(
+                kvv, n_slots, lanes=len(kvv))
+            bs = 16
         scale = 0.3
         m, l, acc = paged_row_stats_lanes(
             q, (k_pool,), v_pool, tables, kv_valid, scale=scale,
-            block_size=8, interpret=True,
+            block_size=bs, interpret=True,
         )
         for lane in range(q.shape[0]):
+            if int(kv_valid[lane]) == 0:
+                # the absorbing empty state
+                assert np.all(np.asarray(l[lane]) == 0.0)
+                assert np.all(np.asarray(acc[lane]) == 0.0)
+                assert np.all(np.asarray(m[lane]) <= -1e29)
+                continue
             m_r, l_r, acc_r = self._ref(q, k_pool, v_pool, tables, kv_valid,
                                         lane, scale)
             # anchor-invariant comparisons: log-mass and normalized BV
@@ -509,6 +562,27 @@ class TestPagedDecodeKernel:
                 np.asarray(acc_r[0] / jnp.maximum(l_r[0], 1e-30)),
                 atol=1e-5, rtol=1e-5,
             )
+
+    def test_pages_per_chunk_is_a_function_of_shapes(self):
+        """The chunk size follows from the pool geometry alone: Qwen2-7B's
+        float32 GQA pool and the absorbed-MLA pools each get their own,
+        within the per-step byte budget and the view."""
+        from repro.kernels.paged_decode import _CHUNK_BYTES, pages_per_chunk
+
+        gqa = dict(hkv=4, splits=(128,), dv=128, block_size=16, itemsize=4,
+                   n_slots=128)
+        mla = dict(hkv=1, splits=(512, 64), dv=512, block_size=16,
+                   itemsize=4, n_slots=128)
+        p_gqa, p_mla = pages_per_chunk(**gqa), pages_per_chunk(**mla)
+        assert (p_gqa, p_mla) == (16, 8)
+        assert pages_per_chunk(**gqa) == p_gqa      # no hidden state
+        for kw, p in ((gqa, p_gqa), (mla, p_mla)):
+            page = (kw["hkv"] * kw["block_size"]
+                    * (sum(kw["splits"]) + kw["dv"]) * kw["itemsize"])
+            assert p & (p - 1) == 0
+            assert p * page <= _CHUNK_BYTES < 2 * p * page
+        assert pages_per_chunk(**{**gqa, "n_slots": 5}) == 5
+        assert pages_per_chunk(**{**gqa, "itemsize": 2}) == 2 * p_gqa
 
     def test_custom_vmap_matches_batched_launch(self):
         from repro.kernels.paged_decode import (
@@ -581,6 +655,23 @@ class TestPagedDecodeKernel:
         for g, r in zip(got, ref):
             np.testing.assert_allclose(
                 np.asarray(g), np.asarray(r), atol=1e-5, rtol=1e-5
+            )
+
+    def test_compiled_kernel_refuses_narrow_pools(self):
+        """A DMA moves whole 128-lane tiles: compiled for TPU, the kernel
+        takes pools stored at ``lane_width`` and refuses a narrower one
+        instead of padding (copying) the pool on every call."""
+        from repro.kernels.paged_decode import (
+            lane_width, paged_row_stats_lanes,
+        )
+
+        q, k_pool, v_pool, tables, kv_valid = self._setup()
+        assert [lane_width(w) for w in (1, 64, 128, 129, 576)] == [
+            128, 128, 128, 256, 640]
+        with pytest.raises(ValueError, match="lane_width"):
+            paged_row_stats_lanes(
+                q, (k_pool,), v_pool, tables, kv_valid, scale=0.3,
+                block_size=8, interpret=False,
             )
 
     def test_split_dim_mismatch_rejected(self):

@@ -797,6 +797,52 @@ class TestPagedDecodeImpl:
             pre = (slice(None),) * info.seq_axis
             assert np.all(np.asarray(arr[(*pre, ZERO_BLOCK)]) == 0.0)
 
+    def test_pages_counter_matches_positions_and_view(self, qwen):
+        """``paged_decode_pages_total``: each paged tick adds, for every
+        lane once, under ``streamed`` the pages its position reaches and
+        under ``tail`` the rest of the view of the launch that decodes it
+        (the first launch's for a lane none decodes) — also in the ticks
+        where demoted lanes take a second launch."""
+        cfg, params = qwen
+        eng = ServeEngine(cfg, params, serve=dataclasses.replace(
+            PAGED_IMPL, telemetry=True))
+        for r in _requests(cfg, 3, seed=37, lo=6, hi=20, max_new=12):
+            eng.submit(r)
+        eng._ensure_exact_step()
+        calls = []
+
+        def spy(step):
+            def fn(storage, tables, tokens, positions, active, nb_view):
+                calls.append((np.asarray(positions), np.asarray(active),
+                              nb_view))
+                return step(storage, tables, tokens, positions, active,
+                            nb_view)
+            return fn
+
+        eng._fused_step = spy(eng._fused_step)
+        eng._exact_step = spy(eng._exact_step)
+        fam = eng.telemetry.metrics.get("paged_decode_pages_total")
+        bs, lanes = PAGED_IMPL.block_size, PAGED_IMPL.max_lanes
+        want = {"streamed": 0, "tail": 0}
+        launches = []
+        for t in range(6):
+            if t == 2:  # pin one decoding lane to the exact program
+                lane = next(ln for ln in eng.lanes if not ln.free)
+                eng._demoted.add(lane.req.uid)
+            eng.tick()
+            idle = lanes - sum(int(act.sum()) for _, act, _ in calls)
+            for pos, act, nb_view in calls:
+                streamed = int(sum(-(-int(p) // bs) for p in pos[act]))
+                want["streamed"] += streamed
+                want["tail"] += (int(act.sum()) + idle) * nb_view - streamed
+                idle = 0
+            launches.append(len(calls))
+            calls.clear()
+            got = {k: fam.labels(kind=k).value for k in want}
+            assert got == want
+        assert max(launches) == 2
+        assert want["streamed"] > 0 and want["tail"] > 0
+
     def test_mla_paged_decode(self):
         """Absorbed MLA runs gather-free through the two-pool kernel
         (latent + rope), token-identical to the gather route."""
@@ -809,6 +855,39 @@ class TestPagedDecodeImpl:
         out, eng = _run(cfg, params, reqs, PAGED_IMPL)
         assert eng.stats()["decode_impl"] == "paged"
         assert ref == out
+
+    def test_mla_pools_stored_lane_padded(self):
+        """The paged kernel's DMAs move whole 128-lane tiles, so every
+        pool is stored lane-padded where it is allocated and the decode
+        program never pads, and so never copies, a pool: no ``pad`` in
+        it reads an operand with the pool's block axis."""
+        cfg = reduced(get_config("deepseek-v2-lite-16b"))
+        params = init_params(model_specs(cfg), jax.random.PRNGKey(0))
+        eng = ServeEngine(cfg, params, serve=PAGED_IMPL)
+        pools = [(info.spec.shape[-1], arr.shape[-1])
+                 for arr, info in zip(eng.kv._storage, eng.kv.infos)
+                 if info.seq_axis is not None]
+        assert pools and all(s % 128 == 0 and s >= w for w, s in pools)
+        assert any(s > w for w, s in pools)  # the rope keys are narrower
+        lanes, nb = eng.max_lanes, eng.kv.num_blocks
+        jaxpr = jax.make_jaxpr(eng._fused_step._jitted)(
+            eng.kv._storage, params, jnp.asarray(eng.sched.tables()[:, :2]),
+            jnp.zeros((lanes, 1, 1), jnp.int32),
+            jnp.full((lanes,), 9, jnp.int32), jnp.ones(lanes, bool))
+
+        def pads(jp):
+            for eqn in jp.eqns:
+                if eqn.primitive.name == "pad":
+                    yield eqn.invars[0].aval.shape
+                for v in eqn.params.values():
+                    for sub in v if isinstance(v, (list, tuple)) else [v]:
+                        sub = getattr(sub, "jaxpr", sub)
+                        if hasattr(sub, "eqns"):
+                            yield from pads(sub)
+
+        padded = list(pads(jaxpr.jaxpr))
+        assert padded  # the query rows are padded to the pool widths
+        assert not [shape for shape in padded if nb in shape], padded
 
     def test_hybrid_family_paged_decode(self):
         """Hybrid (attention + mamba) lanes: attention leaves page, SSM

@@ -24,7 +24,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSh
 
 from repro.core.attention import SSConfig
 from repro.kernels.ops import landmark_summary_op, query_side_op
-from repro.kernels.paged_decode import paged_row_stats_lanes
+from repro.kernels.paged_decode import lane_width, paged_row_stats_lanes
 from repro.kernels.sharded import ss_attention_fused_sharded
 from repro.kernels.ss_attention import landmark_summary, query_side
 
@@ -152,13 +152,17 @@ def test_query_side_backward(one_chip, causal):
 
 
 @pytest.mark.parametrize(
-    "hkv,r,splits,dv",
-    [(KV_HEADS, HEADS // KV_HEADS, (D,), D),   # GQA: one key pool
-     (1, 16, (512, 64), 512)],                 # MLA: latent + rope pools
-    ids=["one_pool", "two_pools_mla"],
+    "lanes,hkv,r,splits,dv,pool_dtype",
+    [(8, KV_HEADS, HEADS // KV_HEADS, (D,), D, BF16),   # GQA: one key pool
+     # MLA: latent + rope pools, the 64-wide rope keys stored 128 wide
+     (8, 1, 16, (512, lane_width(64)), 512, BF16),
+     # the serving deployment: 32 lanes over float32 pools
+     (32, KV_HEADS, HEADS // KV_HEADS, (D,), D, jnp.float32)],
+    ids=["one_pool", "two_pools_mla", "deployment_f32"],
 )
-def test_paged_row_stats_lanes(one_chip, hkv, r, splits, dv):
-    lanes, block, horizon = 8, 16, 2048
+def test_paged_row_stats_lanes(one_chip, lanes, hkv, r, splits, dv,
+                               pool_dtype):
+    block, horizon = 16, 2048                # 128 slots of 16
     num_blocks = lanes * horizon // block + 1
 
     def fn(q, *pools_table_valid):
@@ -170,8 +174,9 @@ def test_paged_row_stats_lanes(one_chip, hkv, r, splits, dv):
 
     args = (
         _shape(one_chip, lanes, hkv, r, sum(splits), dtype=jnp.float32),
-        *(_shape(one_chip, hkv, num_blocks, block, dp) for dp in splits),
-        _shape(one_chip, hkv, num_blocks, block, dv),
+        *(_shape(one_chip, hkv, num_blocks, block, dp, dtype=pool_dtype)
+          for dp in splits),
+        _shape(one_chip, hkv, num_blocks, block, dv, dtype=pool_dtype),
         _shape(one_chip, lanes, horizon // block, dtype=jnp.int32),
         _shape(one_chip, lanes, dtype=jnp.int32),
     )
