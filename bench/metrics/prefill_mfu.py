@@ -1,7 +1,7 @@
 """Model FLOP/s utilization of prefill (%): the operations every prompt
 prefilled in the window requires (``counts.prefill_flops``) over the
 device time inside the engine's ``prefill`` spans and the chip's peak.
-Moves ``ttft_p90_ms``."""
+Moves ``itl_p95_ms``: a prefill stalls every decoding lane."""
 import layer_stats as ls
 
 

@@ -1,5 +1,6 @@
 """Device time inside the engine's ``prefill`` spans per 1000 prompt
-tokens prefilled in the window (ms). Moves ``ttft_p90_ms``."""
+tokens prefilled in the window (ms). Moves ``itl_p95_ms``: a prefill
+stalls every decoding lane."""
 import layer_stats as ls
 
 

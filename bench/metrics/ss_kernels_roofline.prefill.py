@@ -2,7 +2,8 @@
 work every prompt prefilled in the window requires of ``landmark_summary``
 (twice per layer: the prompt's attention and the decode state's seed) and
 ``query_side`` (once per layer), from ``counts``, over the summed device
-time of every Pallas kernel but the paged decode kernel. Moves ``ttft_p90_ms``."""
+time of every Pallas kernel but the paged decode kernel. Moves
+``itl_p95_ms``: a prefill stalls every decoding lane."""
 import layer_stats as ls
 import peaks
 

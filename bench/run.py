@@ -11,6 +11,28 @@ end-to-end metrics; with ``--trace 1`` it profiles the window and reports
 its per-layer metrics, each read by ``bench/metrics/<metric>.py``. A new
 cell, configuration or metric is new files and entries, never an edit.
 
+A configuration names its plain reference: ``"reference": "<module>"``, a
+file ``bench/<module>.py`` that imports nothing of the program. The runner
+decides ``correct`` with it, and calls only this (``REFERENCE_API``):
+
+* a serving reference: ``Dims.from_model(model, max_seq)`` (``model`` and
+  ``max_seq`` from the configuration's ``model`` and ``serve``), hashable;
+  ``score(params, prompt, served, dims, quant)``, the reference's logits
+  before each served token, teacher-forced, as a host array
+  ``(len(served), vocab)``, with ``quant`` ``"f32"`` or ``"fp8"`` (the
+  control); ``served_gap(ref_logits, served)``, per token how far the
+  served token's logit lies below the best.
+* a training reference: ``first_steps(params, batches, model, train,
+  quant="f32")`` -> ``(losses, first_gradient, params_after)``: each
+  step's loss, the first gradient as the optimizer takes it (clipped) and
+  the parameters after the last step, from the host float32 ``params``
+  and the token batches given, with ``model`` and ``train`` the
+  configuration's sections.
+
+So a new architecture's cell is new files alone: ``configs/<name>.json``,
+its ``ref_<arch>.py``, its traffic and its metric readers, and the entries
+appended to ``BENCHMARK.json``.
+
 The run refuses (a non-zero exit, no result line) when JAX finds no TPU, or fewer
 chips than the cell asks for. The last line of standard output is one JSON
 object: ``correct``, ``attempted``, ``failed``, ``metrics``, ``device``, a
@@ -33,6 +55,9 @@ BENCH = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(BENCH)
 RUNNERS = {"backlog": "serve_cell", "open_loop": "serve_cell",
            "train": "train_cell"}
+# What each runner calls on the reference that a configuration names.
+REFERENCE_API = {"serve_cell": ("Dims", "score", "served_gap"),
+                 "train_cell": ("first_steps",)}
 
 
 def load_json(*parts) -> dict:
@@ -41,15 +66,20 @@ def load_json(*parts) -> dict:
 
 
 def find_cell(bench: dict, name: str, base: str = BENCH) -> dict:
-    """The cell's entry, its configuration and traffic files, and the
-    metrics it reports: end-to-end ones listing it (or listing no cells),
-    per-layer ones listing it (or, listing none, moving a metric it
-    reports)."""
+    """The cell's entry, its configuration and traffic files, the
+    reference module its configuration names, and the metrics it
+    reports: end-to-end ones listing it (or listing no cells), per-layer
+    ones listing it (or, listing none, moving a metric it reports)."""
     cells = {w["name"]: w for w in bench["workloads"]}
     if name not in cells:
         raise SystemExit(f"bench: no workload {name!r} in BENCHMARK.json; "
                          f"cells: {sorted(cells)}")
     cell = cells[name]
+    config_file = os.path.join(base, "configs", cell["config"] + ".json")
+    config = load_json(config_file)
+    traffic = load_json(base, "traffic", cell["traffic"] + ".json")
+    if traffic["kind"] not in RUNNERS:
+        raise SystemExit(f"bench: unknown traffic kind {traffic['kind']!r}")
     e2e = [m for m in bench["end_to_end"]
            if name in m.get("workloads", [name])]
     e2e_names = {m["name"] for m in e2e}
@@ -58,21 +88,54 @@ def find_cell(bench: dict, name: str, base: str = BENCH) -> dict:
                               else [])]
     return {
         "cell": cell,
-        "config": load_json(base, "configs", cell["config"] + ".json"),
-        "traffic": load_json(base, "traffic", cell["traffic"] + ".json"),
+        "config": config,
+        "traffic": traffic,
+        "reference": load_reference(config, config_file,
+                                    RUNNERS[traffic["kind"]]),
         "end_to_end": e2e,
         "per_layer": layer,
     }
 
 
+def load_module(path: str, name: str):
+    """The module in the file ``path``, loaded under ``name``."""
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    sys.modules[name] = mod
+    spec.loader.exec_module(mod)
+    return mod
+
+
 def load_reader(metric: str, base: str = BENCH):
     """``bench/metrics/<metric>.py``'s ``read(ctx)``."""
     path = os.path.join(base, "metrics", metric + ".py")
-    spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + metric.replace(".", "_").replace("-", "_"), path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    name = "bench_metric_" + metric.replace(".", "_").replace("-", "_")
+    return load_module(path, name).read
+
+
+def load_reference(config: dict, config_file: str, runner: str,
+                   base: str = BENCH):
+    """The reference module that a configuration names (``"reference"``,
+    a file ``<base>/<name>.py``), with what ``runner`` calls on it
+    (``REFERENCE_API``). There is no default: a configuration that names
+    none, or names a file that is not there or lacks a name the runner
+    calls, stops the run with a message that names its file."""
+    name = config.get("reference")
+    if not name:
+        raise SystemExit(f"bench: {config_file} names no \"reference\"")
+    path = os.path.join(base, name + ".py")
+    if not os.path.isfile(path):
+        raise SystemExit(f"bench: {config_file} names the reference "
+                         f"{name!r}, but there is no file {path}")
+    mod = sys.modules.get(name)  # already imported, as ref_train does
+    if (getattr(mod, "__file__", None) is None
+            or os.path.realpath(mod.__file__) != os.path.realpath(path)):
+        mod = load_module(path, name)
+    missing = [a for a in REFERENCE_API[runner] if not hasattr(mod, a)]
+    if missing:
+        raise SystemExit(f"bench: {path}, the reference that {config_file} "
+                         f"names, lacks {missing}, which {runner} calls")
+    return mod
 
 
 def require_chips(jax, chips: int) -> dict:
@@ -124,10 +187,7 @@ def main(argv=None) -> int:
     device = require_chips(jax, spec["cell"]["chips"])
     print(f"[bench] compile cache {enable_cache(jax)}", file=sys.stderr,
           flush=True)
-    kind = spec["traffic"]["kind"]
-    if kind not in RUNNERS:
-        raise SystemExit(f"bench: unknown traffic kind {kind!r}")
-    runner = importlib.import_module(RUNNERS[kind])
+    runner = importlib.import_module(RUNNERS[spec["traffic"]["kind"]])
     res = runner.run(spec, seed=args.seed, seconds=args.seconds,
                      trace=bool(args.trace), t_start=T_START)
 

@@ -20,9 +20,10 @@ no metric.
 
 Correctness: once the window has closed and the engine is freed, a sample
 drawn from the seed of the finished requests, the longest among them, is
-scored by the float32 reference (``ref_qwen2``) over its prompt and served
-tokens. The number compared is the widest gap by which a served token's
-reference logit lies below the reference's best.
+scored by the float32 reference that the configuration names
+(``ref_qwen2`` for Qwen2) over its prompt and served tokens. The number
+compared is the widest gap by which a served token's reference logit lies
+below the reference's best.
 """
 from __future__ import annotations
 
@@ -38,7 +39,6 @@ import jax.numpy as jnp
 
 import hostload
 import peaks
-import ref_qwen2
 import trace_reduce
 import traffic_gen
 import weights
@@ -81,9 +81,9 @@ def build(config: dict):
     return cfg, serve
 
 
-def dims_of(config: dict) -> ref_qwen2.Dims:
-    return ref_qwen2.Dims.from_model(config["model"],
-                                     config["serve"]["max_seq"])
+def dims_of(ref, config: dict):
+    """The reference's sizes of the configuration's model."""
+    return ref.Dims.from_model(config["model"], config["serve"]["max_seq"])
 
 
 def peak_bytes() -> int:
@@ -249,24 +249,25 @@ def choose_sample(finished: dict, prompts: dict, seed: int) -> list[int]:
     return pick
 
 
-def score_sample(params, sample, prompts, finished, dims,
+def score_sample(ref, params, sample, prompts, finished, dims,
                  control=False) -> dict:
-    """The widest served-token gap over the sample. With ``control``, also
-    the gap of the tokens that the reference computed in float8 puts
-    first, at the same positions (the comparison's control)."""
+    """The widest served-token gap over the sample, by the reference
+    module ``ref``. With ``control``, also the gap of the tokens that the
+    reference computed in float8 puts first, at the same positions (the
+    comparison's control)."""
     out = {"gap": 0.0, "tokens": 0, "requests": len(sample)}
     if control:
         out["control_gap"] = 0.0
     for uid in sample:
         served = finished[uid]
-        ref = ref_qwen2.score(params, prompts[uid], served, dims)
+        logits = ref.score(params, prompts[uid], served, dims, "f32")
         out["gap"] = max(out["gap"],
-                         float(ref_qwen2.served_gap(ref, served).max()))
+                         float(ref.served_gap(logits, served).max()))
         out["tokens"] += len(served)
         if control:
-            low = ref_qwen2.score(params, prompts[uid], served, dims, "fp8")
+            low = ref.score(params, prompts[uid], served, dims, "fp8")
             out["control_gap"] = max(out["control_gap"], float(
-                ref_qwen2.served_gap(ref, low.argmax(-1)).max()))
+                ref.served_gap(logits, low.argmax(-1)).max()))
     return out
 
 
@@ -347,8 +348,9 @@ def run(spec, *, seed: int, seconds: float, trace: bool, t_start: float,
     in_run = {u: finished[u] for u in finished if u in prompts}
     sample = choose_sample(in_run, prompts, seed)
     t_ref = time.perf_counter()
-    scored = score_sample(params, sample, prompts, in_run, dims_of(config),
-                          control)
+    ref = spec["reference"]
+    scored = score_sample(ref, params, sample, prompts, in_run,
+                          dims_of(ref, config), control)
     log(f"reference over {scored['requests']} requests, {scored['tokens']} "
         f"served tokens: {time.perf_counter() - t_ref:.1f}s")
     limit = config["limits"]["served_logit_gap"]

@@ -47,6 +47,12 @@ def test_every_registered_cell_resolves():
         assert spec["end_to_end"] and spec["per_layer"]
         for m in spec["per_layer"]:
             assert callable(run.load_reader(m["name"]))
+        # the reference the configuration names, with what its runner calls
+        ref = spec["reference"]
+        assert ref.__name__ == spec["config"]["reference"]
+        assert os.path.dirname(ref.__file__) == BENCH
+        for name in run.REFERENCE_API[run.RUNNERS[spec["traffic"]["kind"]]]:
+            assert callable(getattr(ref, name)), (cell["name"], name)
 
 
 def test_no_chip_no_result():

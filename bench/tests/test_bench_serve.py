@@ -1,9 +1,11 @@
 """A serving run end to end on the CPU at a small size, past the look for
 a chip: the reference agrees with the program, a sound run is correct,
-the float8 control and a token altered where it is produced are not."""
+the float8 control and a token altered where it is produced are not, and
+a configuration's reference may live in a file of its own anywhere."""
 import json
 import os
 import sys
+import textwrap
 import time
 
 BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -11,17 +13,23 @@ FIXTURE = os.path.join(BENCH, "tests", "fixture")
 sys.path[:0] = [BENCH, os.path.join(os.path.dirname(BENCH), "src")]
 
 import faults  # noqa: E402
+import run  # noqa: E402
 import serve_cell  # noqa: E402
 
+CONFIG = os.path.join(FIXTURE, "configs", "tiny-qwen2.json")
 
-def _spec(traffic="tiny_backlog", **model):
-    with open(os.path.join(FIXTURE, "configs", "tiny-qwen2.json")) as fh:
+
+def _spec(traffic="tiny_backlog", reference=None, **model):
+    with open(CONFIG) as fh:
         config = json.load(fh)
     config["model"].update(model)
     with open(os.path.join(FIXTURE, "traffic", traffic + ".json")) as fh:
         mix = json.load(fh)
     return {"cell": {"name": "tiny", "chips": 1}, "config": config,
-            "traffic": mix, "end_to_end": [], "per_layer": []}
+            "traffic": mix,
+            "reference": reference or run.load_reference(config, CONFIG,
+                                                         "serve_cell"),
+            "end_to_end": [], "per_layer": []}
 
 
 def _run(spec, seed, **kw):
@@ -51,3 +59,30 @@ def test_a_token_altered_where_it_is_produced_is_caught():
         res = _run(_spec(), 5)
     assert not res["correct"]
     assert not res["checks"]["served_logit_gap"]["ok"]
+
+
+def test_a_reference_in_a_file_of_its_own_decides_correct(tmp_path):
+    """A new architecture's cell needs no edit of the harness: a
+    configuration that names a reference module which lives only in
+    another directory is run, and that module decides ``correct``."""
+    (tmp_path / "ref_elsewhere.py").write_text(textwrap.dedent("""
+        import ref_qwen2
+        from ref_qwen2 import Dims, served_gap
+
+        SCORED = []
+
+
+        def score(params, prompt, served, dims, quant):
+            SCORED.append((len(prompt), len(served), quant))
+            return ref_qwen2.score(params, prompt, served, dims, quant)
+    """))
+    with open(CONFIG) as fh:
+        config = dict(json.load(fh), reference="ref_elsewhere")
+    where = str(tmp_path / "configs" / "tiny-elsewhere.json")
+    ref = run.load_reference(config, where, "serve_cell",
+                             base=str(tmp_path))
+    assert ref.__file__ == str(tmp_path / "ref_elsewhere.py")
+    res = _run(_spec(reference=ref), 11)
+    assert res["correct"], res["checks"]
+    assert ref.SCORED and {q for _, _, q in ref.SCORED} == {"f32"}
+    assert sum(n for _, n, _ in ref.SCORED) == res["scored"]["tokens"] > 0

@@ -91,3 +91,18 @@ def test_a_block_keeps_the_tail_of_the_prompt_lengths():
            if r.due_s < 40]
     grid = traffic_gen._lognormal_quantiles(mix["prompt"], mix["sizes"])
     assert len(win) == 48 and sorted(win) == sorted(np.repeat(grid, 2))
+
+
+def test_a_fixed_order_leaves_the_seed_only_the_token_ids():
+    mix = dict(_mix("online_longprompt"), order="fixed")
+    a, b = (traffic_gen.schedule(mix, s, 152064, 40) for s in (5, 2**33 + 1))
+    assert [(r.due_s, len(r.prompt), r.max_new_tokens) for r in a] == [
+        (r.due_s, len(r.prompt), r.max_new_tokens) for r in b]
+    assert [r.prompt for r in a] != [r.prompt for r in b]
+    seeded = dict(mix, order="seeded")
+    c, d = (traffic_gen.schedule(seeded, s, 152064, 40) for s in (5, 6))
+    assert [len(r.prompt) for r in c] != [len(r.prompt) for r in d]
+    # the default is the seeded order, as before
+    default = {k: v for k, v in mix.items() if k != "order"}
+    assert traffic_gen.schedule(seeded, 5, 152064, 40) == \
+        traffic_gen.schedule(default, 5, 152064, 40)

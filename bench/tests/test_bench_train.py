@@ -13,16 +13,20 @@ FIXTURE = os.path.join(BENCH, "tests", "fixture")
 sys.path[:0] = [BENCH, os.path.join(os.path.dirname(BENCH), "src")]
 
 import faults  # noqa: E402
+import run  # noqa: E402
 import train_cell  # noqa: E402
 
 NUMBERS = ("loss_gap", "first_grad_gap", "update_gap")
 
 
 def _spec():
-    with open(os.path.join(FIXTURE, "configs", "tiny-bert.json")) as fh:
+    path = os.path.join(FIXTURE, "configs", "tiny-bert.json")
+    with open(path) as fh:
         config = json.load(fh)
     return {"cell": {"name": "tiny", "chips": 1}, "config": config,
-            "traffic": {"kind": "train"}, "end_to_end": [], "per_layer": []}
+            "traffic": {"kind": "train"},
+            "reference": run.load_reference(config, path, "train_cell"),
+            "end_to_end": [], "per_layer": []}
 
 
 def _run(**kw):

@@ -4,7 +4,8 @@ A traffic file (``bench/traffic/<name>.json``) states its kind and its
 distributions; this one generator reads them all:
 
 ``{"kind": "backlog" | "open_loop", "prompt": {...}, "output": {...},
-  "sizes": K, "rate_per_s": r (open_loop only), "requests": N (backlog)}``
+  "sizes": K, "rate_per_s": r (open_loop only), "requests": N (backlog),
+  "order": "seeded" (the default) | "fixed"}``
 
 ``prompt`` and ``output`` are clipped log-normal length distributions:
 ``{"median": m, "sigma": s, "min": lo, "max": hi}``.
@@ -28,6 +29,11 @@ seed:
   requests due in the window are followed to their first token. Arrival
   ``i`` is due at the sum of the first ``i`` gaps, in wall time from the
   start of the window, whatever the server is doing.
+* ``order: fixed`` draws every block's permutations from one stream that
+  no seed changes: every seed then sends the same sizes and gaps in the
+  same order, and the seed changes only the token ids. For a mix whose
+  tails depend on which prompts arrive while others decode, so that the
+  order, not the server, would set the spread between seeds.
 * token ids are uniform over ``[3, vocab)``.
 """
 from __future__ import annotations
@@ -84,6 +90,8 @@ def schedule(traffic: dict, seed: int, vocab: int, seconds: float) -> list:
     block = _block(traffic, seconds)
     n = request_count(traffic, seconds)
     rng = np.random.default_rng([seed & 0xFFFFFFFF, seed >> 32, n])
+    order = {"seeded": rng, "fixed": np.random.default_rng([0, 0, n])}[
+        traffic.get("order", "seeded")]
     sizes = _lognormal_quantiles(traffic["prompt"], traffic["sizes"])
     # ``block`` picks spread evenly over the sizes, so a block smaller or
     # larger than ``sizes`` keeps the distribution's shape, tail included
@@ -95,10 +103,10 @@ def schedule(traffic: dict, seed: int, vocab: int, seconds: float) -> list:
     gaps *= span / gaps.sum()
     prompt_lens, out_lens, due = [], [], []
     for b in range(n // block):
-        prompt_lens.extend(rng.permutation(grid).tolist())
-        out_lens.extend(rng.permutation(outs).tolist())
+        prompt_lens.extend(order.permutation(grid).tolist())
+        out_lens.extend(order.permutation(outs).tolist())
         # an open loop's block b arrives in [b * seconds, (b + 1) * seconds)
-        g = rng.permutation(gaps)
+        g = order.permutation(gaps)
         due.extend((b * span + np.concatenate([[0.0], np.cumsum(g)[:-1]]))
                    .tolist())
     return [

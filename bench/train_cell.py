@@ -18,14 +18,15 @@ wait, so all of that work counts over all of that time, and no step is
 cut at the close.
 
 Correctness: once the window has closed and the trainer is freed, the
-float32 reference (``ref_train``) takes the same weights and the same three
-batches. Compared: each step's loss (relative gap), the first gradient as
-the optimizer took it (from its first moment after step 1, divided by
-``1 - beta1``), and the parameters' change over the three steps; the last
-two leaf by leaf, the gap between the program's norm and the reference's
-over the larger of the reference's norm of that leaf and of the median
-leaf, worst leaf. Leaves whose reference gradient is under a thousandth of
-the median leaf's are left out of both.
+float32 reference that the configuration names (``ref_train``) takes the
+same weights and the same three batches. Compared: each step's loss
+(relative gap), the first gradient as the optimizer took it (from its
+first moment after step 1, divided by ``1 - beta1``), and the parameters'
+change over the three steps; the last two leaf by leaf, the gap between
+the program's norm and the reference's over the larger of the reference's
+norm of that leaf and of the median leaf, worst leaf. Leaves whose
+reference gradient is under a thousandth of the median leaf's are left out
+of both.
 """
 from __future__ import annotations
 
@@ -44,7 +45,6 @@ import jax.numpy as jnp
 
 import hostload
 import peaks
-import ref_train
 import trace_reduce
 import weights
 from repro.configs.base import ModelConfig, ShapeConfig, TrainConfig
@@ -138,11 +138,11 @@ def held(got: dict, limits: dict) -> dict:
             for name in ("loss_gap", "first_grad_gap", "update_gap")}
 
 
-def compare(config, start, moment1, after, losses, data, beta1,
+def compare(ref, config, start, moment1, after, losses, data, beta1,
             control=False):
-    """The first steps against the reference. With ``control``, also the
-    readings of the reference computed with float8 inputs in the
-    program's place (the comparison's control)."""
+    """The first steps against the reference module ``ref``. With
+    ``control``, also the readings of the reference computed with float8
+    inputs in the program's place (the comparison's control)."""
     m, t = config["model"], config["train"]
     batches = [data.batch(i)["tokens"] for i in range(FIRST_STEPS)]
 
@@ -150,13 +150,13 @@ def compare(config, start, moment1, after, losses, data, beta1,
         return (leaf_norms(host(g)),
                 leaf_norms(jax.tree.map(np.subtract, host(after), start)))
 
-    ref_losses, ref_g, ref_after = ref_train.first_steps(start, batches, m, t)
+    ref_losses, ref_g, ref_after = ref.first_steps(start, batches, m, t)
     g_ref, d_ref = norms(start, ref_g, ref_after)
     g_prog = [n / (1 - beta1) for n in leaf_norms(moment1)]
     d_prog = leaf_norms(jax.tree.map(np.subtract, after, start))
     out = readings(g_prog, d_prog, losses, g_ref, d_ref, ref_losses)
     if control:
-        low_losses, low_g, low_after = ref_train.first_steps(
+        low_losses, low_g, low_after = ref.first_steps(
             start, batches, m, t, quant="fp8")
         g_low, d_low = norms(start, low_g, low_after)
         out["control"] = readings(g_low, d_low, low_losses, g_ref, d_ref,
@@ -250,8 +250,8 @@ def run(spec, *, seed: int, seconds: float, trace: bool, t_start: float,
                "chips": 1}
 
     t_ref = time.perf_counter()
-    got = compare(config, start, moment1, after, losses, data, tcfg.beta1,
-                  control)
+    got = compare(spec["reference"], config, start, moment1, after, losses,
+                  data, tcfg.beta1, control)
     log(f"reference {time.perf_counter() - t_ref:.1f}s: {got}")
     checks = held(got, config["limits"])
     out = {

@@ -4,13 +4,15 @@ The benchmark owns the weight distribution; the program only supplies the
 tree of parameter shapes it expects. Every matrix is drawn as in GPT-2
 (Radford et al., 2019): normal with standard deviation 0.02, and the two
 projections that write into the residual stream (``w_o``, ``w_down``)
-scaled by ``1 / sqrt(2 * layers)``. Norm gains are ones. Random weights
-drawn with the program's own fan-in rule (``1 / sqrt(shape[-2])``, which
-for a ``(d_model, heads, head_dim)`` projection is ``1 / sqrt(heads)``)
-give attention scores so sharp that sixteen layers amplify rounding into
-logits that share nothing across routes; this distribution keeps the
-random model well conditioned, so a comparison with a reference means
-something. Speed does not depend on the values.
+scaled by ``1 / sqrt(2 * layers)``. Norm gains are ones: every leaf whose
+name starts with ``norm`` or ends in ``_norm`` (``norm_attn``,
+``norm_kv``, ``final_norm``, ...). Random weights drawn with the program's
+own fan-in rule (``1 / sqrt(shape[-2])``, which for a ``(d_model, heads,
+head_dim)`` projection is ``1 / sqrt(heads)``) give attention scores so
+sharp that sixteen layers amplify rounding into logits that share nothing
+across routes; this distribution keeps the random model well conditioned,
+so a comparison with a reference means something. Speed does not depend on
+the values.
 """
 from __future__ import annotations
 
@@ -22,12 +24,16 @@ import jax.numpy as jnp
 
 STD = 0.02
 RESIDUAL_OUT = ("w_o", "w_down")
-ONES = ("norm_attn", "norm_mlp", "final_norm")
 
 
 def _leaf_name(path) -> str:
     last = path[-1]
     return str(getattr(last, "key", getattr(last, "name", last)))
+
+
+def is_norm_gain(name: str) -> bool:
+    """A norm's gain, drawn as ones, by its name in the tree."""
+    return name.startswith("norm") or name.endswith("_norm")
 
 
 def leaf_std(name: str, num_layers: int) -> float:
@@ -54,7 +60,7 @@ def make_weights(shapes, seed: int, num_layers: int, dtype=jnp.bfloat16):
     def draw(key):
         out = []
         for shape, name, key_id in plan:
-            if name in ONES:
+            if is_norm_gain(name):
                 out.append(jnp.ones(shape, dtype))
                 continue
             sub = jax.random.fold_in(key, key_id)
